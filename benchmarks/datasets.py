@@ -30,13 +30,10 @@ def _sigma(spec, x, y) -> float:
     best, best_acc = base * 0.5, -1.0
     for sc in _CV_SCALES:
         sigma = max(base * sc, 1e-3)
-        try:
-            res = sc_rb(jnp.asarray(x[:n_cv]), SCRBConfig(
-                n_clusters=spec.k, n_grids=64, sigma=sigma,
-                kmeans_replicates=2, solver_iters=150))
-            acc = metrics.accuracy(res.labels, y[:n_cv])
-        except Exception:
-            continue
+        res = sc_rb(jnp.asarray(x[:n_cv]), SCRBConfig(
+            n_clusters=spec.k, n_grids=64, sigma=sigma,
+            kmeans_replicates=2, solver_iters=150))
+        acc = metrics.accuracy(res.labels, y[:n_cv])
         if acc > best_acc:
             best, best_acc = sigma, acc
     _SIGMA_CACHE[key] = best

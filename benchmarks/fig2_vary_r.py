@@ -35,6 +35,8 @@ def main() -> None:
     ap.add_argument("--scale", type=float, default=0.02)
     ap.add_argument("--out", default="bench_results/fig2.json")
     args = ap.parse_args()
+    from repro.utils import use_compile_cache
+    use_compile_cache()
     res = run(scale=args.scale)
     import os
     os.makedirs(os.path.dirname(args.out), exist_ok=True)

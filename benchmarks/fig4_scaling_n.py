@@ -82,6 +82,8 @@ def main() -> None:
     ap.add_argument("--solver", default="auto")
     ap.add_argument("--out", default="bench_results/fig4.json")
     args = ap.parse_args()
+    from repro.utils import use_compile_cache
+    use_compile_cache()
     ns = [n for n in (1_000, 2_000, 4_000, 8_000, 16_000, 32_000, 64_000,
                       128_000, 256_000)
           if n <= args.max_n]

@@ -230,6 +230,8 @@ def main() -> None:
     ap.add_argument("--gate", action="store_true",
                     help="exit non-zero when an overhead budget is blown")
     args = ap.parse_args()
+    from repro.utils import use_compile_cache
+    use_compile_cache()
     if args.run_child:
         _child(args.n, args.repeats)
         return

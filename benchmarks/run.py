@@ -22,6 +22,8 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true",
                     help="smallest scales (CI smoke)")
     args = ap.parse_args()
+    from repro.utils import use_compile_cache
+    use_compile_cache()
     scale = 0.004 if args.quick else args.scale
     os.makedirs("bench_results", exist_ok=True)
     rows = ["name,us_per_call,derived"]

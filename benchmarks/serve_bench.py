@@ -297,6 +297,8 @@ def main() -> None:
     ap.add_argument("--gate", action="store_true",
                     help="exit non-zero on any serving regression")
     args = ap.parse_args()
+    from repro.utils import use_compile_cache
+    use_compile_cache()
     res = run(args.smoke, args.requests, args.waves, args.seed)
     failures = gate(res)
     res["gate_failures"] = failures

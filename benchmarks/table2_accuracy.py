@@ -74,6 +74,8 @@ def main() -> None:
     ap.add_argument("--rank", type=int, default=256)
     ap.add_argument("--out", default="bench_results/table2.json")
     args = ap.parse_args()
+    from repro.utils import use_compile_cache
+    use_compile_cache()
     res = run(scale=args.scale, rank=args.rank)
     import os
     os.makedirs(os.path.dirname(args.out), exist_ok=True)

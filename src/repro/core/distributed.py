@@ -37,7 +37,7 @@ from repro.core import streaming
 from repro.core.kmeans import KMeansResult, _plusplus_init
 from repro.kernels import ops
 from repro.launch.mesh import data_axes
-from repro.utils import StageTimer, shard_map_compat
+from repro.utils import StageTimer
 
 _data_axes = data_axes   # back-compat alias (moved to repro.launch.mesh)
 
@@ -64,7 +64,7 @@ def make_gram_matvec(mesh: Mesh, idx: jax.Array, rowscale: jax.Array,
     row_spec = P(axes if len(axes) > 1 else axes[0])
 
     @functools.partial(
-        shard_map_compat, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(row_spec[0], None), P(row_spec[0], None), row_spec),
         check_vma=False,   # kernels allocate unvarying scan carries internally
         out_specs=P(row_spec[0], None))
@@ -89,26 +89,27 @@ def make_gram_matvec(mesh: Mesh, idx: jax.Array, rowscale: jax.Array,
     return lambda u: gram(u, idx, rowscale)
 
 
-def make_degree_pass(mesh: Mesh, idx: jax.Array, d: int, d_g: int,
-                     impl: str = "auto", compress: bool = False,
+def make_degree_pass(mesh: Mesh, d: int, d_g: int, impl: str = "auto",
+                     compress: bool = False,
                      chunk_size: Optional[int] = None):
-    """The Eq. 6 degree pass deg = Z(Zᵀ1), also emitting the replicated (D,)
-    bin occupancies Zᵀ1 that the first product computes anyway — the fitted
-    model's degree dual, captured at no extra collective sweep. Same
-    blocking/collective structure as ``make_gram_matvec``.
+    """The Eq. 6 degree pass deg = Z(Zᵀ1) as a function of the row-sharded
+    (N, R) ELL, also emitting the replicated (D,) bin occupancies Zᵀ1 that
+    the first product computes anyway — the fitted model's degree dual,
+    captured at no extra collective sweep. Same blocking/collective
+    structure as ``make_gram_matvec``. The ELL is an argument, so a
+    ``jax.jit`` of the pass never embeds it as a constant.
     """
     axes = data_axes(mesh)
     row_spec = P(axes if len(axes) > 1 else axes[0])
-    r = idx.shape[1]
-    inv_sqrt_r = jnp.float32(1.0 / np.sqrt(r))
 
     @functools.partial(
-        shard_map_compat, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(row_spec[0], None),),
         check_vma=False,
         out_specs=(row_spec, P(None)))
     def degpass(idx_local):
-        n_local = idx_local.shape[0]
+        n_local, r = idx_local.shape
+        inv_sqrt_r = jnp.float32(1.0 / np.sqrt(r))
         ones = jnp.ones((n_local, 1), jnp.float32)
         scale_local = jnp.full((n_local,), inv_sqrt_r, jnp.float32)
         if chunk_size is None:
@@ -131,7 +132,7 @@ def make_degree_pass(mesh: Mesh, idx: jax.Array, d: int, d_g: int,
         # undo the 1/√R value folding: raw occupancies (exact up to ~2 ulp)
         return y[:, 0], q[:, 0] * jnp.sqrt(jnp.float32(r))
 
-    return lambda: degpass(idx)
+    return degpass
 
 
 def make_zt_matvec(mesh: Mesh, idx: jax.Array, rowscale: jax.Array,
@@ -142,7 +143,7 @@ def make_zt_matvec(mesh: Mesh, idx: jax.Array, rowscale: jax.Array,
     row_spec = P(axes if len(axes) > 1 else axes[0])
 
     @functools.partial(
-        shard_map_compat, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(row_spec[0], None), P(row_spec[0], None), row_spec),
         check_vma=False,
         out_specs=P(None, None))
@@ -176,7 +177,7 @@ def make_sharded_reduce(mesh: Mesh, fn: Callable, *,
         specs = tuple(P(row_axis, *([None] * (t.ndim - 1))) for t in tall)
         out_specs = jax.tree_util.tree_map(lambda _: P(), init)
 
-        @functools.partial(shard_map_compat, mesh=mesh, in_specs=specs,
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=specs,
                            out_specs=out_specs, check_vma=False)
         def local(*tl):
             m = tl[0].shape[0]
@@ -261,7 +262,7 @@ def distributed_kmeans(
         pool = jax.block_until_ready(jnp.take(u, pool_idx, axis=0))
     rep_keys = jax.random.split(jax.random.fold_in(key, 1), n_replicates)
 
-    @functools.partial(shard_map_compat, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(row_spec, P(None, None, None)),
                        out_specs=(P(), P(), P()), check_vma=False)
     def _stats(u_local, cents_r):
@@ -311,7 +312,7 @@ def distributed_kmeans(
         _, _, inertia = _stats(u_in, cents_r)
         return cents_r, inertia
 
-    @functools.partial(shard_map_compat, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(row_spec, P(None, None)),
                        out_specs=P(row_axis), check_vma=False)
     def _assign(u_local, cents):

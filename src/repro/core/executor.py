@@ -334,13 +334,19 @@ def execute(
     tracing to this run and exports the Chrome trace on exit, completed fits
     feed ``repro_fits_total``/``repro_fit_rows_total``, and a host/device
     memory watermark lands in ``diagnostics["memory"]``.
+
+    Every matmul the fit traces runs at ``Precision.HIGHEST``: the TPU's
+    default float32 matmul is a single bf16 pass, whose ~1e-3 relative
+    error keeps the eigensolver's residuals far above ``SolverOptions.tol``
+    (on a v5e chip, poker-sized LOBPCG stopped at its 300-iteration cap
+    with residuals of 1e-2). CPU matmuls are float32 either way.
     """
     cfg = config
     if plan is None:
         plan = plan_from_config(cfg)
     if final_stage not in ("normalize", "kmeans"):
         raise ValueError(f"unknown final_stage {final_stage!r}")
-    with obs_trace.tracing(cfg.trace):
+    with obs_trace.tracing(cfg.trace), jax.default_matmul_precision("highest"):
         with obs_memory.Watermark() as wm:
             with obs_trace.span("fit", placement=plan.placement,
                                 residency=plan.residency) as root:

@@ -168,9 +168,10 @@ def row_normalize_chunks(chunks: Chunks, *, prefetch: bool = True,
                          measure: Optional[dict] = None):
     """Chunked Alg. 2 step 4: unit-ℓ₂ rows, one chunk on device at a time.
 
-    Row normalization is row-local, so this is bit-identical to
-    ``row_normalize`` on the concatenated array for any chunking (it runs
-    the very same jax computation per chunk).
+    Row normalization is row-local, so this agrees with ``row_normalize``
+    on the concatenated array for any chunking: the same jax computation
+    runs per chunk, and only the reduction order XLA picks for the row norm
+    at a given row count can move the result by a few ulp.
     """
     from repro.core.streaming import ChunkedDense
     out = [

@@ -34,6 +34,7 @@ model's.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 from typing import Any, Optional
 
@@ -93,6 +94,19 @@ def _oos_predict_impl(fm, dual, proj, cents, x, *, laplacian: bool,
 _oos_embed = jax.jit(_oos_embed_impl, static_argnames=("laplacian",))
 _oos_predict = jax.jit(_oos_predict_impl, static_argnames=("laplacian",
                                                            "impl"))
+
+
+def _per_row_shard(fn, mesh, n_state: int, out_rank: int):
+    """``fn(*state, x)`` run on each row shard of ``mesh`` with the state
+    replicated: GSPMD cannot partition the Mosaic kernels inside the
+    out-of-sample ops, and every one of them is row-local."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.core import rowmatrix
+    spec = rowmatrix.MeshRows._row_spec(mesh)
+    return jax.jit(jax.shard_map(
+        fn, mesh=mesh, in_specs=(P(),) * n_state + (spec,),
+        out_specs=P(*spec[:out_rank]), check_vma=False))
 
 
 @dataclasses.dataclass
@@ -288,7 +302,7 @@ class SCRBModel:
 
         With a mesh the O(D·K) state is replicated (it is tiny — that is the
         whole point of the artifact) and batches are row-sharded exactly like
-        ``MeshRows``, so the jitted OOS ops run SPMD with no code changes.
+        ``MeshRows``; the OOS ops then run once per row shard.
         """
         fm = self.feature_map
         dual = jnp.asarray(self.degree_dual)
@@ -347,9 +361,12 @@ class SCRBModel:
         """
         fm, dual, proj, _, sharding, n_shards = \
             self._serve_setup(mesh, with_centroids=False)
+        lap = self.laplacian_normalize
+        embed = functools.partial(_oos_embed, laplacian=lap) if mesh is None \
+            else _per_row_shard(functools.partial(_oos_embed_impl,
+                                                  laplacian=lap), mesh, 3, 2)
         outs = [
-            np.asarray(_oos_embed(fm, dual, proj, xb,
-                                  laplacian=self.laplacian_normalize))[:rows]
+            np.asarray(embed(fm, dual, proj, xb))[:rows]
             for xb, rows in self._serve_batches(x, batch_size, sharding,
                                                 n_shards)
         ]
@@ -367,10 +384,13 @@ class SCRBModel:
                 "stage); use transform() or refit with final_stage='kmeans'")
         fm, dual, proj, cents, sharding, n_shards = \
             self._serve_setup(mesh, with_centroids=True)
+        static = dict(laplacian=self.laplacian_normalize,
+                      impl=self.config.impl)
+        label = functools.partial(_oos_predict, **static) if mesh is None \
+            else _per_row_shard(functools.partial(_oos_predict_impl,
+                                                  **static), mesh, 4, 1)
         outs = [
-            np.asarray(_oos_predict(fm, dual, proj, cents, xb,
-                                    laplacian=self.laplacian_normalize,
-                                    impl=self.config.impl))[:rows]
+            np.asarray(label(fm, dual, proj, cents, xb))[:rows]
             for xb, rows in self._serve_batches(x, batch_size, sharding,
                                                 n_shards)
         ]

@@ -399,11 +399,15 @@ class MeshRows:
                 f"under placement='single'")
         mesh = plan.mesh
         fitted = fm.fit(key, np.asarray(x))
-        row_shard = cls._row_sharding(mesh)
-        xs = jax.device_put(jnp.asarray(x, jnp.float32), row_shard)
-        with mesh:
-            idx = jax.jit(fitted.transform, out_shardings=row_shard)(xs)
-            idx = jax.block_until_ready(idx)
+        row_spec = cls._row_spec(mesh)
+        xs = jax.device_put(np.asarray(x, np.float32),
+                            cls._row_sharding(mesh))
+        # per-shard transform: GSPMD cannot partition a Mosaic kernel, and
+        # the map is row-local
+        transform = jax.jit(jax.shard_map(
+            lambda f, xl: f.transform(xl), mesh=mesh,
+            in_specs=(P(), row_spec), out_specs=row_spec, check_vma=False))
+        idx = jax.block_until_ready(transform(fitted, xs))
         return FittedFeatures(fitted, idx)
 
     @classmethod
@@ -419,9 +423,9 @@ class MeshRows:
             # one pass yields both the degrees and the replicated (D,) bin
             # occupancies — the fitted-model degree dual, kept for free
             deg, counts = jax.jit(make_degree_pass(
-                mesh, idx, d, fm.d_g, plan.impl,
+                mesh, d, fm.d_g, plan.impl,
                 compress=plan.collective_compress,
-                chunk_size=plan.chunk_size))()
+                chunk_size=plan.chunk_size))(idx)
             if plan.laplacian_normalize:
                 rowscale = 1.0 / jnp.sqrt(cfg.n_grids * jnp.maximum(deg, 1e-8))
             else:
@@ -484,9 +488,13 @@ class MeshRows:
         return self._gram_cache
 
     def matvec(self, v):
-        with self.mesh:
-            return ops.z_matmul(self.idx, v, self.rowscale, d_g=self.d_g,
-                                impl=self.impl)
+        spec = self._row_spec(self.mesh)
+        zv = jax.shard_map(
+            lambda i, vv, sc: ops.z_matmul(i, vv, sc, d_g=self.d_g,
+                                           impl=self.impl),
+            mesh=self.mesh, in_specs=(spec, P(), P(spec[0])),
+            out_specs=spec, check_vma=False)
+        return zv(self.idx, v, self.rowscale)
 
     def matvec_tall(self, v):
         return self.matvec(v)   # already row-sharded (idx carries the spec)
@@ -550,28 +558,32 @@ class MeshRows:
         so = cfg.solver_options
         precond = _solver_precond(cfg, self.deg)
         if so.solver in ("lobpcg", "lobpcg_host") and 3 * k <= self.n:
+            from repro.core.distributed import make_gram_matvec
             b = eigensolver.lobpcg_block_width(self.n, k, so.buffer)
+
+            # the ELL and row scales are arguments of the jitted solve: a
+            # closure over them would embed the (N, R) ELL in the program
+            def solve(xs, precond, idx, rowscale):
+                matvec = make_gram_matvec(
+                    self.mesh, idx, rowscale, self.d, self.d_g, self.impl,
+                    compress=self.compress, chunk_size=self.chunk_size)
+                return eigensolver.lobpcg(
+                    matvec, xs, max_iters=so.iters, tol=so.tol,
+                    precond=precond, stable_tol=so.stable_tol, stable_k=k,
+                    conv_k=k)
+
             with self.mesh:
-                matvec = self._gram_fn()
                 if x0 is not None:
                     start = jnp.asarray(
                         eigensolver.prepare_start_block(x0, self.n, b, key))
                 else:
                     start = jax.random.normal(key, (self.n, b), jnp.float32)
                 x0s = jax.device_put(start, self._row_sharding(self.mesh))
-                solve = functools.partial(
-                    eigensolver.lobpcg, matvec,
-                    max_iters=so.iters, tol=so.tol,
-                    stable_tol=so.stable_tol, stable_k=k, conv_k=k)
-                if precond is None:
-                    eig = jax.jit(solve)(x0s)
-                else:
-                    # the (N,) diagonal rides the row sharding; passing it
-                    # as a traced arg keeps one jit cache entry per shape
-                    tvec = jax.device_put(jnp.asarray(precond, jnp.float32),
-                                          self._vec_sharding(self.mesh))
-                    eig = jax.jit(lambda xs, t: solve(xs, precond=t))(
-                        x0s, tvec)
+                # the (N,) diagonal rides the row sharding
+                tvec = None if precond is None else jax.device_put(
+                    jnp.asarray(precond, jnp.float32),
+                    self._vec_sharding(self.mesh))
+                eig = jax.jit(solve)(x0s, tvec, self.idx, self.rowscale)
                 u = jax.block_until_ready(eig.vectors[:, :k])
             return eigensolver.EigResult(eig.theta[:k], u, eig.resnorms[:k],
                                          eig.iterations)

@@ -20,7 +20,8 @@ def _kmeans_assign_kernel(x_ref, c_ref, lab_ref, dist_ref):
     c = c_ref[...]                                      # (K, d)
     x2 = jnp.sum(x * x, axis=-1, keepdims=True)         # (bn, 1)
     c2 = jnp.sum(c * c, axis=-1)                        # (K,)
-    xc = jax.lax.dot(x, c.T, preferred_element_type=jnp.float32)
+    xc = jax.lax.dot(x, c.T, precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=jnp.float32)
     d2 = x2 - 2.0 * xc + c2[None, :]                    # (bn, K)
     lab_ref[...] = jnp.argmin(d2, axis=-1, keepdims=True).astype(jnp.int32)
     dist_ref[...] = jnp.maximum(jnp.min(d2, axis=-1, keepdims=True), 0.0)

@@ -44,6 +44,11 @@ def _pad_rows(a: jax.Array, mult: int, fill=0):
     return jnp.pad(a, widths, constant_values=fill), n
 
 
+def _pad_cols(a: jax.Array, mult: int):
+    pad = (-a.shape[1]) % mult
+    return jnp.pad(a, ((0, 0), (0, pad))) if pad else a
+
+
 def _largest_divisor(n: int, cap: int) -> int:
     for c in range(min(cap, n), 0, -1):
         if n % c == 0:
@@ -156,7 +161,7 @@ def rb_binning(
 ) -> jax.Array:
     """ELL column indices of the hashed RB feature matrix: int32 (N, R)."""
     impl = _resolve(impl)
-    r = widths.shape[0]
+    r, d = widths.shape
     if impl == "xla":
         return _rb_binning_xla(
             x, widths, biases, hash_a, hash_c,
@@ -164,11 +169,20 @@ def rb_binning(
         )
     block_n = pick_block_rows("rb_binning", x.shape[0], block_rows)
     xp, n = _pad_rows(x, block_n)
+    # walk d in lane-aligned blocks; padded dimensions hash to zero
+    # (x = 0, width 1, bias 0, multiplier 0)
+    block_d = min(d, _rb_kernel.BLOCK_D)
+    pad_d = (-d) % block_d
+    as_i32 = lambda a: jax.lax.bitcast_convert_type(a, jnp.int32)
+    cols_t = lambda a, fill: jnp.pad(a.T, ((0, pad_d), (0, 0)),
+                                     constant_values=fill)
     out = _rb_kernel.rb_binning_pallas(
-        xp, widths, biases, hash_a, hash_c,
+        jnp.pad(xp, ((0, 0), (0, pad_d))),
+        cols_t(widths, 1.0), cols_t(biases, 0.0), cols_t(as_i32(hash_a), 0),
+        as_i32(hash_c)[None, :],
         d_g=d_g,
         block_n=block_n,
-        block_r=_largest_divisor(r, 8),
+        block_d=block_d,
         interpret=not _on_tpu(),
     )
     return out[:n]
@@ -260,6 +274,40 @@ def _zt_matmul_xla(idx, u, rowscale, *, d, r_chunk):
     return acc
 
 
+def _ell_block_n(n: int, block_rows: Optional[int]) -> int:
+    """Row tile of the ELL kernels. Rows sit on the 128-wide lane axis
+    there, so a tile that does not cover every row is a multiple of 128."""
+    block_n = pick_block_rows("ell_spmm", n, block_rows)
+    return block_n if block_n >= n else max(block_n, 128)
+
+
+def _ell_operands(idx, rowscale, block_n):
+    """(idx_t (R, N_pad), s (1, N_pad), n): the kernels' transposed row
+    layout; padded rows get scale 0, so they contribute nothing."""
+    idx_p, n = _pad_rows(idx, block_n)
+    s_p, _ = _pad_rows(rowscale.astype(jnp.float32), block_n)
+    return idx_p.T, s_p[None, :], n
+
+
+def _tall_t(u, block_n):
+    """(N, K) → (K_pad, N_pad): rows padded to the tile, K to the 8-row
+    sublane tile."""
+    return _pad_cols(_pad_rows(u, block_n)[0], 8).T
+
+
+def _factor_chunks(v, r, d_g):
+    """(D, K) → (R, nc, K_pad, dc), the z kernel's per-grid bin chunks."""
+    dc = ell_spmm.dg_chunk(d_g)
+    vp = _pad_cols(v, 8)
+    return vp.reshape(r, d_g // dc, dc, vp.shape[1]).transpose(0, 1, 3, 2)
+
+
+def _factor_unchunk(q4, k):
+    """(R, nc, K_pad, dc) → (D, K), inverse of ``_factor_chunks``."""
+    r, nc, kp, dc = q4.shape
+    return q4.transpose(0, 1, 3, 2).reshape(r * nc * dc, kp)[:, :k]
+
+
 def z_matmul(
     idx: jax.Array,
     v: jax.Array,
@@ -274,15 +322,14 @@ def z_matmul(
     r = idx.shape[1]
     if impl == "xla":
         return _z_matmul_xla(idx, v, rowscale, r_chunk=_largest_divisor(r, 8))
-    block_n = pick_block_rows("ell_spmm", idx.shape[0], block_rows)
-    idx_p, n = _pad_rows(idx, block_n)
-    s_p, _ = _pad_rows(rowscale, block_n)
-    out = ell_spmm.z_matmul_pallas(
-        idx_p, v, s_p, d_g=d_g,
-        block_n=block_n, block_r=_largest_divisor(r, 4),
+    block_n = _ell_block_n(idx.shape[0], block_rows)
+    idx_t, s, n = _ell_operands(idx, rowscale, block_n)
+    y_t = ell_spmm.z_matmul_pallas(
+        idx_t, _factor_chunks(v, r, d_g), s, d_g=d_g,
+        block_n=block_n, block_r=ell_spmm.pick_block_r(r),
         interpret=not _on_tpu(),
     )
-    return out[:n]
+    return y_t[:v.shape[1], :n].T.astype(v.dtype)
 
 
 def zt_matmul(
@@ -300,21 +347,25 @@ def zt_matmul(
     r = idx.shape[1]
     if impl == "xla":
         return _zt_matmul_xla(idx, u, rowscale, d=d, r_chunk=_largest_divisor(r, 8))
-    block_n = pick_block_rows("ell_spmm", idx.shape[0], block_rows)
-    idx_p, _ = _pad_rows(idx, block_n)
-    u_p, _ = _pad_rows(u, block_n)
-    s_p, _ = _pad_rows(rowscale, block_n)   # pad scale with 0 ⇒ no contribution
-    return ell_spmm.zt_matmul_pallas(
-        idx_p, u_p, s_p, d, d_g=d_g,
-        block_n=block_n, block_r=_largest_divisor(r, 4),
+    assert d == r * d_g, (d, r, d_g)
+    block_n = _ell_block_n(idx.shape[0], block_rows)
+    idx_t, s, _ = _ell_operands(idx, rowscale, block_n)
+    q4 = ell_spmm.zt_matmul_pallas(
+        idx_t, _tall_t(u, block_n), s, d_g=d_g,
+        block_n=block_n, block_r=ell_spmm.pick_block_r(r),
         interpret=not _on_tpu(),
     )
+    return _factor_unchunk(q4, u.shape[1]).astype(u.dtype)
 
 
-# Upper bound on the VMEM the fused Gram kernel's (D, K) resident
-# accumulator may claim; above this the dispatch falls back to the
-# two-kernel pair (the intermediate then lives in HBM, as before).
-GRAM_FUSE_VMEM_BYTES = 6 * 2 ** 20
+# Upper bound on the VMEM the fused Gram kernel's resident (D, K)
+# intermediate may claim (``ell_spmm.gram_vmem_bytes``); above it the
+# dispatch composes the two single-product kernels (the intermediate then
+# lives in HBM). The v5e compiler's default scoped-VMEM limit is 16 MiB and
+# the launch's streamed blocks and one-hot tiles need ~0.1 MiB beside the
+# intermediate: at R = 256, K ≤ 16 the 8 MiB of d_g = 512 fuses, the
+# 16 MiB of d_g = 1024 does not.
+GRAM_FUSE_VMEM_BYTES = 12 * 2 ** 20
 
 
 def gram_matmul(
@@ -333,32 +384,30 @@ def gram_matmul(
     (``ell_spmm.gram_matmul_pallas``): the ELL index strip is streamed
     through VMEM once per phase and the (D, K) intermediate stays
     VMEM-resident between the scatter and gather phases instead of
-    round-tripping through HBM. When ``D·K·4`` exceeds
-    ``GRAM_FUSE_VMEM_BYTES`` the dispatch silently composes the two
-    existing kernels — identical math, same tiling. The XLA route is the
-    reference composition of the two XLA paths.
+    round-tripping through HBM. When that intermediate exceeds
+    ``GRAM_FUSE_VMEM_BYTES`` the dispatch composes the two single-product
+    kernels — identical math, same tiling. The XLA route is the reference
+    composition of the two XLA paths.
     """
     impl = _resolve(impl)
-    r = idx.shape[1]
+    r, k = idx.shape[1], u.shape[1]
     if impl == "xla":
         rc = _largest_divisor(r, 8)
         q = _zt_matmul_xla(idx, u, rowscale, d=d, r_chunk=rc)
         return _z_matmul_xla(idx, q, rowscale, r_chunk=rc)
-    if d * u.shape[1] * 4 > GRAM_FUSE_VMEM_BYTES:
+    if ell_spmm.gram_vmem_bytes(r, k, d_g) > GRAM_FUSE_VMEM_BYTES:
         q = zt_matmul(idx, u, rowscale, d, d_g=d_g, impl="pallas",
                       block_rows=block_rows)
         return z_matmul(idx, q, rowscale, d_g=d_g, impl="pallas",
                         block_rows=block_rows)
-    block_n = pick_block_rows("ell_spmm", idx.shape[0], block_rows)
-    idx_p, n = _pad_rows(idx, block_n)
-    u_p, _ = _pad_rows(u, block_n)
-    s_p, _ = _pad_rows(rowscale, block_n)   # pad scale with 0 ⇒ no contribution
-    out = ell_spmm.gram_matmul_pallas(
-        idx_p, u_p, s_p, d, d_g=d_g,
-        block_n=block_n, block_r=_largest_divisor(r, 4),
+    block_n = _ell_block_n(idx.shape[0], block_rows)
+    idx_t, s, n = _ell_operands(idx, rowscale, block_n)
+    y_t = ell_spmm.gram_matmul_pallas(
+        idx_t, _tall_t(u, block_n), s, d_g=d_g,
+        block_n=block_n, block_r=ell_spmm.pick_block_r(r),
         interpret=not _on_tpu(),
     )
-    return out[:n]
+    return y_t[:k, :n].T.astype(u.dtype)
 
 
 # --------------------------------------------------------------------------
@@ -369,7 +418,8 @@ def gram_matmul(
 def _kmeans_assign_xla(x, centroids):
     x2 = jnp.sum(x * x, axis=-1, keepdims=True)
     c2 = jnp.sum(centroids * centroids, axis=-1)
-    d2 = x2 - 2.0 * x @ centroids.T + c2[None, :]
+    xc = jnp.matmul(x, centroids.T, precision=jax.lax.Precision.HIGHEST)
+    d2 = x2 - 2.0 * xc + c2[None, :]
     return (
         jnp.argmin(d2, axis=-1).astype(jnp.int32),
         jnp.maximum(jnp.min(d2, axis=-1), 0.0),

@@ -5,11 +5,16 @@ point into one bin per random grid. The TPU adaptation (DESIGN.md §3.1) makes
 the feature space static via multiply-shift hashing, so the kernel is pure
 VPU element-wise math over VMEM tiles — no hash-map, no dynamic shapes.
 
-Tiling: grid (N/block_n, R/block_r). Each program loads an x tile
-(block_n, d), the (block_r, d) slice of grid parameters, and writes a
-(block_n, block_r) tile of int32 feature indices. VMEM per program ≈
-block_n·d·4 + 3·block_r·d·4 + block_n·block_r·4 bytes — sized well under the
-~16 MiB v5e VMEM budget for the default blocks.
+Tiling: grid (N/block_n, d_pad/block_d). Each program loads an x tile
+(block_n, block_d), the (block_d, R) slices of the transposed grid
+parameters, and accumulates the (block_n, R) int32 hash of all R grids in
+the output block, which stays resident across the dimension axis. A
+``lax.fori_loop`` walks the block's dimensions one at a time, so the live
+intermediate is one (block_n, R) tile at any d (mnist's d = 780 included).
+
+The hash runs in int32 with wrapping multiply/add and a logical right
+shift: mod 2³² these are the same bits as the uint32 form of
+``kernels/ref.py``, which the TPU compiler cannot reduce.
 """
 from __future__ import annotations
 
@@ -21,67 +26,88 @@ from jax.experimental import pallas as pl
 
 from repro.kernels.ref import HASH_MIX
 
+# HASH_MIX reinterpreted as int32 (same bits mod 2³²)
+_MIX_I32 = int(HASH_MIX) - (1 << 32)
+# Input dimensions per program: the x tile's lane width once d > 128
+BLOCK_D = 128
+
 
 def _rb_binning_kernel(
-    x_ref,        # (block_n, d) float32
-    w_ref,        # (block_r, d) float32
-    b_ref,        # (block_r, d) float32
-    a_ref,        # (block_r, d) uint32
-    c_ref,        # (block_r, 1) uint32
-    out_ref,      # (block_n, block_r) int32
+    x_ref,        # (block_n, block_d) float32
+    w_ref,        # (block_d, R) float32
+    b_ref,        # (block_d, R) float32
+    a_ref,        # (block_d, R) int32 (uint32 bits)
+    c_ref,        # (1, R) int32 (uint32 bits)
+    out_ref,      # (block_n, R) int32
     *,
     d_g: int,
-    block_r: int,
 ):
+    kd = pl.program_id(1)
     shift = 32 - int(d_g).bit_length() + 1
-    x = x_ref[...]                                     # (bn, d)
-    w = w_ref[...]                                     # (br, d)
-    b = b_ref[...]
-    a = a_ref[...]
-    c = c_ref[...][:, 0]                               # (br,)
-    # (bn, br, d) bin coordinates
-    bins = jnp.floor((x[:, None, :] - b[None, :, :]) / w[None, :, :])
-    bins_u = bins.astype(jnp.int32).astype(jnp.uint32)
-    h = jnp.sum(bins_u * a[None, :, :], axis=-1, dtype=jnp.uint32)
-    h = (h + c[None, :]) * HASH_MIX
-    local = (h >> jnp.uint32(shift)).astype(jnp.int32)  # (bn, br) in [0, d_g)
-    g0 = pl.program_id(1) * block_r
-    offs = (g0 + jax.lax.iota(jnp.int32, block_r)) * d_g
-    out_ref[...] = local + offs[None, :]
+    x = x_ref[...]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+
+    def body(j, h):
+        # column j of the tile as (block_n, 1): a masked lane sum adds only
+        # zeros to x[:, j], so it is exact
+        xj = jnp.sum(jnp.where(lane == j, x, 0.0), axis=1, keepdims=True)
+        w = w_ref[pl.ds(j, 1), :]                      # (1, R)
+        b = b_ref[pl.ds(j, 1), :]
+        a = a_ref[pl.ds(j, 1), :]
+        bins = jnp.floor((xj - b) / w).astype(jnp.int32)
+        return h + bins * a
+
+    h = jax.lax.fori_loop(0, x.shape[1], body,
+                          jnp.zeros(out_ref.shape, jnp.int32))
+
+    @pl.when(kd == 0)
+    def _init():
+        out_ref[...] = h
+
+    @pl.when(kd != 0)
+    def _acc():
+        out_ref[...] += h
+
+    @pl.when(kd == pl.num_programs(1) - 1)
+    def _finish():
+        hh = (out_ref[...] + c_ref[...]) * jnp.int32(_MIX_I32)
+        local = jax.lax.shift_right_logical(hh, jnp.int32(shift))
+        offs = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 1) * d_g
+        out_ref[...] = local + offs
 
 
 @functools.partial(
-    jax.jit, static_argnames=("d_g", "block_n", "block_r", "interpret")
+    jax.jit, static_argnames=("d_g", "block_n", "block_d", "interpret")
 )
 def rb_binning_pallas(
-    x: jax.Array,
-    widths: jax.Array,
-    biases: jax.Array,
-    hash_a: jax.Array,
-    hash_c: jax.Array,
+    x: jax.Array,         # (N, d_pad) float32
+    widths_t: jax.Array,  # (d_pad, R) float32
+    biases_t: jax.Array,  # (d_pad, R) float32
+    hash_a_t: jax.Array,  # (d_pad, R) int32
+    hash_c: jax.Array,    # (1, R) int32
     *,
     d_g: int,
     block_n: int = 256,
-    block_r: int = 8,
-    interpret: bool = True,
+    block_d: int = 128,
+    interpret: bool = False,
 ) -> jax.Array:
-    """Pallas entry point; caller (ops.py) guarantees divisible tilings."""
+    """Pallas entry point; the caller (ops.py) pads N to ``block_n`` and d
+    to ``block_d``, transposes the grid parameters and bit-casts the hash
+    constants to int32."""
     n, d = x.shape
-    r = widths.shape[0]
-    assert n % block_n == 0 and r % block_r == 0, (n, r, block_n, block_r)
-    grid = (n // block_n, r // block_r)
-    kern = functools.partial(_rb_binning_kernel, d_g=d_g, block_r=block_r)
+    r = widths_t.shape[1]
+    assert n % block_n == 0 and d % block_d == 0, (n, d, block_n, block_d)
+    kern = functools.partial(_rb_binning_kernel, d_g=d_g)
+    par = pl.BlockSpec((block_d, r), lambda i, kd: (kd, 0))
     return pl.pallas_call(
         kern,
-        grid=grid,
+        grid=(n // block_n, d // block_d),   # out accumulates over axis 1
         in_specs=[
-            pl.BlockSpec((block_n, d), lambda i, g: (i, 0)),
-            pl.BlockSpec((block_r, d), lambda i, g: (g, 0)),
-            pl.BlockSpec((block_r, d), lambda i, g: (g, 0)),
-            pl.BlockSpec((block_r, d), lambda i, g: (g, 0)),
-            pl.BlockSpec((block_r, 1), lambda i, g: (g, 0)),
+            pl.BlockSpec((block_n, block_d), lambda i, kd: (i, kd)),
+            par, par, par,
+            pl.BlockSpec((1, r), lambda i, kd: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((block_n, block_r), lambda i, g: (i, g)),
+        out_specs=pl.BlockSpec((block_n, r), lambda i, kd: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, r), jnp.int32),
         interpret=interpret,
-    )(x, widths, biases, hash_a, hash_c[:, None])
+    )(x, widths_t, biases_t, hash_a_t, hash_c)

@@ -17,19 +17,19 @@ from __future__ import annotations
 import jax
 import numpy as np
 
-from repro.utils import make_mesh_compat
+from repro.utils import make_auto_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_auto_mesh(shape, axes)
 
 
 def make_host_mesh() -> jax.sharding.Mesh:
     """Whatever devices exist locally, as a 1-D data mesh (tests/examples)."""
     n = len(jax.devices())
-    return make_mesh_compat((n,), ("data",))
+    return make_auto_mesh((n,), ("data",))
 
 
 def partition_devices(mesh: jax.sharding.Mesh) -> tuple:

@@ -44,9 +44,9 @@ def _device_sync() -> None:
     outputs should block on those instead (``StageTimer.timed`` does)."""
     try:
         import jax
-        jax.block_until_ready(jax.device_put(0.0))
-    except Exception:       # jax not importable / no devices: tracing still works
-        pass
+    except ImportError:     # tracing works without jax; nothing to sync
+        return
+    jax.block_until_ready(jax.device_put(0.0))
 
 
 class _NullSpan:

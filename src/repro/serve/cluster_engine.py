@@ -12,11 +12,13 @@ therefore batches on rows, not slots:
   ops are row-local, so zero rows in the pad tail never contaminate real
   rows; outputs are sliced back per request and are bit-identical to direct
   ``model.predict`` (gated in ``benchmarks/serve_bench.py``).
-- **Donated staging ring** — each bucket shape owns a small ring of reusable
-  host staging buffers (``_StagingRing``); batches are assembled into a ring
-  slot, shipped H2D once, and (off CPU) donated to the compiled call, so
-  steady-state serving allocates no new host buffers per request. The ring's
-  ``allocations`` counter is the bench's "steady-state allocations" gate.
+- **Staging ring** — each bucket shape owns a small ring of reusable host
+  staging buffers (``_StagingRing``); batches are assembled into a ring
+  slot and shipped H2D once, so steady-state serving allocates no new host
+  buffers per request. The ring's ``allocations`` counter is the bench's
+  "steady-state allocations" gate. The device copy is not donated to the
+  cell: no predict output has the (bucket, d) float32 shape of the batch,
+  so XLA cannot reuse it and only warns.
 - **Multi-model LRU** — many artifacts are registered by name
   (``load_model`` takes an npz path or a fitted model; re-loading a name is
   a hot-swap). Device-resident O(D·K) states live in an LRU
@@ -63,9 +65,6 @@ class EngineConfig:
     max_resident_models: int = 4          # LRU capacity (count)
     device_budget_bytes: Optional[int] = None   # LRU capacity (bytes)
     ring_slots: int = 2                   # staging buffers per bucket shape
-    donate: str = "auto"                  # "auto" | "on" | "off" — donate the
-    # H2D batch buffer to the compiled call; "auto" enables it off-CPU only
-    # (CPU XLA can't donate and warns)
     max_batch_rows: Optional[int] = None  # coalescing cap per device launch;
     # None → top bucket
     impl: Optional[str] = None            # kmeans_assign impl override
@@ -75,8 +74,6 @@ class EngineConfig:
     # tracing off; REPRO_TRACE=<path> is the env equivalent.
 
     def __post_init__(self):
-        if self.donate not in ("auto", "on", "off"):
-            raise ValueError(f"donate must be auto|on|off, got {self.donate!r}")
         if tuple(sorted(self.buckets)) != tuple(self.buckets) or \
                 len(self.buckets) == 0 or self.buckets[0] < 1:
             raise ValueError(f"buckets must be ascending and ≥1: {self.buckets}")
@@ -188,10 +185,6 @@ class ClusterEngine:
             "engine_batch_rows", "Real rows per coalesced device batch.",
             ("model",), buckets=obs_metrics.log_buckets(1.0, 2 ** 20, 2))
         self.total_compiles = 0
-        if self.config.donate == "auto":
-            self._donate = jax.default_backend() != "cpu"
-        else:
-            self._donate = self.config.donate == "on"
         if self.config.trace:
             obs_trace.enable(self.config.trace)
 
@@ -266,16 +259,14 @@ class ClusterEngine:
         mdl = self._models[name]
         xs = jax.ShapeDtypeStruct((bucket, dim), jnp.float32)
         if mode == "predict":
-            kw = {"donate_argnums": (4,)} if self._donate else {}
             fn = jax.jit(_model._oos_predict_impl,
-                         static_argnames=("laplacian", "impl"), **kw)
+                         static_argnames=("laplacian", "impl"))
             cell = fn.lower(res.fm, res.dual, res.proj, res.cents, xs,
                             laplacian=mdl.laplacian_normalize,
                             impl=self.config.impl or mdl.config.impl).compile()
         else:
-            kw = {"donate_argnums": (3,)} if self._donate else {}
             fn = jax.jit(_model._oos_embed_impl,
-                         static_argnames=("laplacian",), **kw)
+                         static_argnames=("laplacian",))
             cell = fn.lower(res.fm, res.dual, res.proj, xs,
                             laplacian=mdl.laplacian_normalize).compile()
         self._cells[key] = cell

@@ -4,6 +4,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import logging
+import os
 import time
 import warnings
 from typing import Any, Callable, Dict, Iterator
@@ -176,24 +177,22 @@ def prefetch_to_device(
     yield cur
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` across jax versions.
-
-    jax ≥ 0.6 exposes ``jax.shard_map(..., check_vma=)``; older releases have
-    ``jax.experimental.shard_map.shard_map(..., check_rep=)`` (same flag,
-    renamed). Keeping the shim here lets the distributed layer run on both.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+def make_auto_mesh(shape, axes) -> "jax.sharding.Mesh":
+    """``jax.make_mesh`` with every axis ``Auto``: GSPMD propagates the
+    shardings, and the SPMD pipeline names its collectives explicitly in
+    ``shard_map``."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
-def make_mesh_compat(shape, axes) -> "jax.sharding.Mesh":
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def use_compile_cache() -> None:
+    """Persistent compilation cache for an entry point (never set at
+    import): ``$JAX_COMPILATION_CACHE_DIR`` when it is set — JAX reads it
+    itself — else ``.jax_cache/`` at the root of this checkout. The path is
+    part of the cache key, so it is never built from a temp name, a PID or
+    the clock."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        root = os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.dirname(os.path.abspath(__file__)))))
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(root, ".jax_cache"))

@@ -22,8 +22,8 @@ from repro.core import rb, graph
 from repro.data.synthetic import make_rings
 from repro.utils import fold_key
 
-from repro.utils import make_mesh_compat
-mesh = make_mesh_compat((8,), ("data",))
+from repro.utils import make_auto_mesh
+mesh = make_auto_mesh((8,), ("data",))
 x, y = make_rings(1024, 2, seed=0)
 cfg = SCRBConfig(n_clusters=2, n_grids=128, sigma=0.15, d_g=4096,
                  kmeans_replicates=2, seed=0)

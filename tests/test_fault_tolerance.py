@@ -47,9 +47,9 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.train import checkpoint as ckpt
 
-from repro.utils import make_mesh_compat
-mesh_a = make_mesh_compat((2, 4), ("data", "model"))
-mesh_b = make_mesh_compat((8,), ("data",))
+from repro.utils import make_auto_mesh
+mesh_a = make_auto_mesh((2, 4), ("data", "model"))
+mesh_b = make_auto_mesh((8,), ("data",))
 
 tree = {"w": jnp.arange(64.0).reshape(8, 8), "b": jnp.arange(8.0)}
 sharded = {

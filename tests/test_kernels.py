@@ -25,6 +25,7 @@ def _rb_inputs(key, n, d, r, d_g):
     (100, 3, 16, 128),     # n not divisible by tile
     pytest.param(256, 7, 4, 256, marks=pytest.mark.slow),
     pytest.param(513, 16, 32, 512, marks=pytest.mark.slow),  # odd n, wide d
+    (40, 150, 8, 64),      # d over one 128-wide block: hashes accumulate
 ])
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
 def test_rb_binning_matches_ref(n, d, r, d_g, impl):
@@ -39,7 +40,7 @@ def test_rb_binning_matches_ref(n, d, r, d_g, impl):
     (64, 4, 64, 8),
     (100, 8, 128, 3),      # ragged n
     pytest.param(256, 16, 64, 32, marks=pytest.mark.slow),
-    # r not divisible by block_r=4 -> falls to divisor
+    # no multiple of 8 divides r -> one block of all R grids
     pytest.param(300, 12, 256, 5, marks=pytest.mark.slow),
 ])
 @pytest.mark.parametrize("impl", ["xla", "pallas"])

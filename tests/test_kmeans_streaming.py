@@ -17,8 +17,10 @@ from repro.kernels import ops
 
 
 def test_row_normalize_chunks_bit_identical():
-    """Row normalization is row-local ⇒ chunked result is bit-identical to
-    the single-shot one for any chunking, prefetch on or off."""
+    """Row normalization is row-local ⇒ the chunked result equals the
+    single-shot one for any chunking, prefetch on or off. XLA may pick a
+    different reduction order for the row norm at a different row count,
+    so equality is to a few float32 ulp, not bitwise."""
     rng = np.random.default_rng(0)
     u = rng.normal(size=(503, 6)).astype(np.float32)
     want = np.asarray(row_normalize(jnp.asarray(u)))
@@ -27,7 +29,7 @@ def test_row_normalize_chunks_bit_identical():
             cd = streaming.ChunkedDense.from_array(u, sizes)
             got = row_normalize_chunks(cd, prefetch=prefetch)
             assert got.chunk_sizes == cd.chunk_sizes
-            assert np.array_equal(got.to_array(), want)
+            np.testing.assert_array_max_ulp(got.to_array(), want, maxulp=4)
 
 
 def test_streaming_kmeans_agrees_with_kmeans_on_blobs():

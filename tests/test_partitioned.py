@@ -158,8 +158,8 @@ def test_partition_devices_mesh_slice():
     import jax
 
     from repro.launch.mesh import partition_devices
-    from repro.utils import make_mesh_compat
+    from repro.utils import make_auto_mesh
     n = len(jax.devices())
-    mesh = make_mesh_compat((n, 1), ("data", "model"))
+    mesh = make_auto_mesh((n, 1), ("data", "model"))
     devs = partition_devices(mesh)
     assert len(devs) == n

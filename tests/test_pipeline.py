@@ -16,14 +16,18 @@ def rings():
 
 
 def test_scrb_smoke_fast():
-    """Fast-tier pipeline smoke: non-convex rings at reduced scale, with
-    per-stage timings and deterministic output. The full-scale qualitative
-    claims (vs exact SC, convergence in R, ...) run under --runslow.
+    """Fast-tier pipeline smoke: two 2-d Gaussian blobs at reduced scale,
+    with per-stage timings and deterministic output. The non-convex rings
+    and the full-scale qualitative claims (vs exact SC, convergence in R,
+    ...) run under --runslow: at this scale a two-ring RB graph is
+    marginal — an exact dense eigendecomposition of the same RB graph
+    splits the outer ring for roughly one grid draw in six — so a fast-tier
+    accuracy gate on rings pins a lucky seed, not the pipeline.
 
-    Deliberately the same (N, R, d_g) as tests/test_streaming's end-to-end
-    case so the jitted stages compile once per pytest session.
+    Deliberately the same (N, d, R, d_g, K) as tests/test_streaming's
+    end-to-end case so the jitted stages compile once per pytest session.
     """
-    x, y = make_rings(600, 2, seed=0)
+    x, y = make_blobs(600, 2, 2, seed=0)
     cfg = SCRBConfig(n_clusters=2, n_grids=96, sigma=0.15, d_g=4096,
                      solver_tol=1e-3, kmeans_replicates=2, seed=7)
     res = sc_rb(jnp.asarray(x), cfg)

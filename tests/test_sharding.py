@@ -13,17 +13,17 @@ from jax.sharding import PartitionSpec as P
 from repro.models import sharding as sh
 
 
-from repro.utils import make_mesh_compat
+from repro.utils import make_auto_mesh
 
 
 @pytest.fixture(scope="module")
 def mesh():
     # single-device abstract-ish mesh: rules only inspect shapes/names
-    return make_mesh_compat((1, 1), ("data", "model"))
+    return make_auto_mesh((1, 1), ("data", "model"))
 
 
 def test_pick_axes_divisibility():
-    m = make_mesh_compat((1, 1), ("data", "model"))
+    m = make_auto_mesh((1, 1), ("data", "model"))
     assert sh.pick_axes(m, 64, ("model",)) == ("model",)
     # with axis size 1 everything divides
     assert sh.pick_axes(m, 7, ("model",)) == ("model",)
@@ -68,8 +68,8 @@ from repro.configs import get_config, SHAPES, smoke_config
 from repro.launch.specs import build_cell
 import dataclasses
 
-from repro.utils import make_mesh_compat
-mesh = make_mesh_compat((2, 2, 2), ("pod", "data", "model"))
+from repro.utils import make_auto_mesh
+mesh = make_auto_mesh((2, 2, 2), ("pod", "data", "model"))
 results = {}
 shape = dataclasses.replace(SHAPES["train_4k"], seq_len=128, global_batch=8)
 decode = dataclasses.replace(SHAPES["decode_32k"], seq_len=256, global_batch=8)
