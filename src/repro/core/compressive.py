@@ -281,8 +281,9 @@ def compressive_embed(z, k: int, key, cfg, *,
             z, k, key, cfg, laplacian_normalize=laplacian_normalize)
         res = np.asarray(out.resnorms[:k])
         resnorm_max = float(res.max()) if res.size else 0.0
-        sp.set(iterations=int(out.iterations), resnorm_max=resnorm_max,
-               filter_degree=out.filter_degree, signals=out.signals)
+        sp.set_metadata(iterations=int(out.iterations),
+                        resnorm_max=resnorm_max,
+                        filter_degree=out.filter_degree, signals=out.signals)
     _SOLVES_TOTAL.inc(solver="compressive")
     _SOLVER_ITERS.observe(int(out.iterations), solver="compressive")
     _SOLVER_RESNORM.set(resnorm_max, solver="compressive")

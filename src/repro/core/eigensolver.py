@@ -846,7 +846,7 @@ def top_k_eigenpairs(
         iters = int(out.iterations)
         res = np.asarray(out.resnorms)
         resnorm_max = float(res.max()) if res.size else 0.0
-        sp.set(iterations=iters, resnorm_max=resnorm_max)
+        sp.set_metadata(iterations=iters, resnorm_max=resnorm_max)
     _SOLVES_TOTAL.inc(solver=solver)
     _SOLVER_ITERS.observe(iters, solver=solver)
     _SOLVER_RESNORM.set(resnorm_max, solver=solver)

@@ -124,10 +124,11 @@ class SCRBConfig:
     #   {"ell_spmm": 256}); None keeps the defaults. Applied to every kernel
     #   dispatch of the run via ops.block_rows_overrides.
     trace: Optional[str] = None
-    # ^ Chrome-trace output path: enables repro.obs tracing for this fit and
-    #   exports the trace (Perfetto-viewable) on completion. None (default)
-    #   keeps tracing off; REPRO_TRACE=<path> enables it process-wide
-    #   instead. A run-local setting, never part of the saved artifact.
+    # ^ directory for a jax.profiler trace of this fit (program spans on
+    #   the host plane beside the device lanes, Perfetto-viewable); none is
+    #   started when a session is already live. None (default) starts none;
+    #   REPRO_TRACE=<dir> traces process-wide instead. A run-local setting,
+    #   never part of the saved artifact.
     # -- typed option groups (canonical; see repro.core.options) ------------
     solver_options: Optional[SolverOptions] = None
     # ^ None → SolverOptions() defaults (or the deprecated flat kwargs).
@@ -330,8 +331,8 @@ def execute(
 
     Observability: the whole run executes under a root ``fit`` span (stage
     spans from ``StageTimer`` nest inside; a partitioned run's per-partition
-    sub-fits land on their worker-thread tracks), ``cfg.trace`` scopes
-    tracing to this run and exports the Chrome trace on exit, completed fits
+    sub-fits land on their worker-thread lanes), ``cfg.trace`` records this
+    run into a profiler trace in that directory, completed fits
     feed ``repro_fits_total``/``repro_fit_rows_total``, and a host/device
     memory watermark lands in ``diagnostics["memory"]``.
 
@@ -355,7 +356,7 @@ def execute(
                     keep_embedding=keep_embedding, keep_state=keep_state)
                 solver = res.diagnostics.get(
                     "solver", cfg.solver_options.solver)
-                root.set(solver=solver)
+                root.set_metadata(solver=solver)
         res.diagnostics.setdefault("memory", wm.as_dict())
     n_rows = (res.labels.shape[0] if res.labels is not None
               else res.embedding.shape[0] if res.embedding is not None

@@ -258,9 +258,9 @@ def execute_partitioned(x, cfg, plan, *, final_stage: str = "kmeans",
         dev = devices[i % len(devices)]
         ctx = (jax.default_device(dev)
                if len(devices) > 1 else contextlib.nullcontext())
-        # this span closes on the worker thread, so each partition lands on
-        # its own Perfetto track (workers > 1 ⇒ parallel lanes), temporally
-        # nested under the root "fit" span on the main track
+        # this span runs on the worker thread, so each partition lands on
+        # its own lane of the profiler's host plane (workers > 1 ⇒ parallel
+        # lanes), inside the root "fit" span's time on the main thread
         with obs_trace.span("partition_fit", partition=i, device=str(dev),
                             rows=_part_rows(xp)):
             with ctx:

@@ -3,12 +3,12 @@
 Three pillars, wired through every layer of the SC_RB stack:
 
 ``repro.obs.trace``
-    Thread-safe hierarchical span tracer with JAX-aware closing (optional
-    device sync on span exit so spans measure device work, not dispatch),
-    span attributes, per-thread tracks, and Chrome-trace-event JSON export
-    viewable in Perfetto / ``chrome://tracing``. Off by default; enabled via
-    ``SCRBConfig(trace=...)``, ``EngineConfig(trace=...)``, or the
-    ``REPRO_TRACE=<path>`` environment variable.
+    Program spans as ``jax.profiler.TraceAnnotation``s with attributes:
+    recorded on the profiler's host plane, on the device lanes' clock,
+    whenever a profiler session is live, and a no-op otherwise.
+    ``SCRBConfig(trace=<dir>)``, ``EngineConfig(trace=<dir>)`` and
+    ``REPRO_TRACE=<dir>`` start a session into a directory (Perfetto opens
+    the result).
 
 ``repro.obs.metrics``
     Process-wide registry (``repro.obs.metrics.REGISTRY``) of labeled
@@ -19,13 +19,11 @@ Three pillars, wired through every layer of the SC_RB stack:
     a dict update under a lock, nanoseconds next to any device work.
 
 ``repro.obs.memory``
-    Device-memory and host-RSS watermark sampling with per-span peak
-    deltas; the tracer samples it on span enter/exit when configured.
+    Device-memory and host-RSS watermark sampling; the executor records a
+    fit's watermark in ``FitResult.diagnostics["memory"]``.
 
-Kill switch: ``REPRO_OBS_DISABLED=1`` disables both pillars at import time
-(spans become no-ops, instruments stop recording) — the honest "no
-observability" baseline the CI overhead gate (``benchmarks/obs_bench.py``)
-compares against.
+Kill switch: ``REPRO_OBS_DISABLED=1`` disables spans and metrics at import
+time (spans become no-ops, instruments stop recording).
 """
 from repro.obs import memory, metrics, trace
 

@@ -6,9 +6,8 @@ Replaces the scattered residency bookkeeping with one sampling surface:
 - ``host_peak_rss_bytes()`` — lifetime RSS high-water mark (``getrusage``).
 - ``device_bytes_in_use()`` — live device allocation, when the backend
   exposes ``Device.memory_stats()`` (GPU/TPU; ``None`` on CPU).
-- ``sample()``            — one dict with all of the above; what the tracer
-  attaches to spans (``Tracer(memory=True)``) and the executor folds into
-  ``FitResult.diagnostics["memory"]``.
+- ``sample()``            — one dict with all of the above; what the
+  executor folds into ``FitResult.diagnostics["memory"]``.
 - ``Watermark``           — scoped peak-delta helper for tests/benchmarks.
 """
 from __future__ import annotations

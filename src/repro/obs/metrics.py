@@ -21,8 +21,7 @@ engine uses a private registry per instance so concurrent engines (tests
 spin up many) don't cross-talk; ``GET /metrics`` concatenates both in
 Prometheus text-exposition format 0.0.4.
 
-``REPRO_OBS_DISABLED=1`` turns every instrument into a no-op at import —
-the honest no-observability baseline for the CI overhead gate.
+``REPRO_OBS_DISABLED=1`` turns every instrument into a no-op at import.
 """
 from __future__ import annotations
 

@@ -1,56 +1,53 @@
-"""Thread-safe hierarchical span tracer with Chrome-trace-event export.
+"""Program spans on the profiler's clock.
 
-Design constraints, in order:
+``span(name, **attrs)`` is a ``jax.profiler.TraceAnnotation``. Whenever a
+``jax.profiler`` session is live — an operator's own, a benchmark's, or one
+started by the switches below — the span is recorded on the profiler's host
+plane, on the same clock as the device planes, so every device lane can be
+read against the program span the host was in. The profiler records the
+thread of each span itself: a partitioned fit's workers show as lanes of
+their own. Attributes become the event's metadata; ``set_metadata(**attrs)``
+adds more before the span closes (the eigensolver's iteration count).
 
-1. **Disabled is free.** Tracing is off by default and the fit/serve hot
-   paths call ``span(...)`` unconditionally, so the disabled path must cost
-   one attribute check and return a shared no-op context manager — no
-   allocation, no lock. The CI overhead gate (``benchmarks/obs_bench.py``)
-   pins the disabled-tracing fit wall-clock within 1% of a build with
-   observability compiled out entirely (``REPRO_OBS_DISABLED=1``).
-2. **Spans measure device work, not dispatch.** JAX dispatch is async: a
-   span closed right after ``jit_fn(x)`` returns has timed the *enqueue*.
-   With ``sync=True`` (the default for stage-level spans) the span exit
-   performs a device sync barrier first, so the recorded duration covers
-   the device work launched inside the span. Spans that deliberately time
-   only the issue side (the prefetch H2D spans) pass ``sync=False``.
-3. **Threads are tracks.** Every span records the thread it closed on; the
-   Chrome export emits per-thread track metadata, so the partitioned fit's
-   thread-pool workers render as parallel lanes in Perfetto, nested under
-   the root ``fit`` span on the main track.
+With no session live, ``span`` returns a shared no-op after one
+``TraceAnnotation.is_enabled()`` check: nothing is recorded, no attribute is
+encoded and no file is written. A span does not synchronise with the device:
+device work shows on the device lanes, where it ran.
 
-The module-level tracer (``TRACER``) is what the pipeline instruments
-against; tests construct private ``Tracer`` instances. ``REPRO_TRACE=<path>``
-enables the module tracer at import and registers an atexit Chrome-JSON
-export to that path.
+Switches, each starting a session into a directory with
+``create_perfetto_trace=True`` (Perfetto opens the ``perfetto_trace.json.gz``
+it writes beside the ``.xplane.pb``, device lanes included) unless a session
+is already live, in which case spans record into that one. Their sessions
+leave out the profiler's Python tracer, which records every Python call and
+would grow a long run's trace without bound; the host plane keeps the
+program's spans and JAX's own host events:
+
+- ``tracing(dir)``: for one scope, reentrant (``SCRBConfig(trace=dir)``);
+- ``start(dir)``: until the process exits (``EngineConfig(trace=dir)``);
+- ``REPRO_TRACE=<dir>``: until the process exits, from the first fit or
+  engine of the process.
+
+``REPRO_OBS_DISABLED=1`` turns spans into no-ops and the switches off.
 """
 from __future__ import annotations
 
 import atexit
 import contextlib
-import json
 import os
-import threading
-import time
-from typing import Any, Dict, List, Optional
+from typing import Optional
+
+import jax
+from jax.profiler import TraceAnnotation
 
 _DISABLED = os.environ.get("REPRO_OBS_DISABLED", "") not in ("", "0")
+ENV_DIR: Optional[str] = os.environ.get("REPRO_TRACE") or None
 
-
-def _device_sync() -> None:
-    """Best-effort device sync barrier: dispatch a trivial transfer and
-    block on it. On single-stream backends (CPU, one-stream GPU queues)
-    this drains previously dispatched work; callers that hold the actual
-    outputs should block on those instead (``StageTimer.timed`` does)."""
-    try:
-        import jax
-    except ImportError:     # tracing works without jax; nothing to sync
-        return
-    jax.block_until_ready(jax.device_put(0.0))
+# Whether a profiler session is recording spans now.
+active = TraceAnnotation.is_enabled
 
 
 class _NullSpan:
-    """Shared no-op span for the disabled path (and a safe ``set`` sink)."""
+    """Shared no-op span: what ``span`` returns with no session live."""
 
     __slots__ = ()
 
@@ -60,268 +57,66 @@ class _NullSpan:
     def __exit__(self, *exc) -> bool:
         return False
 
-    def set(self, **attrs) -> None:
+    def set_metadata(self, **attrs) -> None:
         pass
 
 
 NULL_SPAN = _NullSpan()
 
 
-class Span:
-    """One open span; context-manager. ``set(**attrs)`` adds attributes any
-    time before exit (e.g. results only known mid-stage)."""
-
-    __slots__ = ("name", "attrs", "sync", "t0_ns", "dur_ns", "tid",
-                 "thread_name", "depth", "_tracer", "_mem0")
-
-    def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any],
-                 sync: Optional[bool]):
-        self.name = name
-        self.attrs = attrs
-        self.sync = tracer.sync if sync is None else sync
-        self._tracer = tracer
-        self.t0_ns = 0
-        self.dur_ns = 0
-        self.tid = 0
-        self.thread_name = ""
-        self.depth = 0
-        self._mem0 = None
-
-    def set(self, **attrs) -> None:
-        self.attrs.update(attrs)
-
-    def __enter__(self) -> "Span":
-        tr = self._tracer
-        stack = tr._stack()
-        self.depth = len(stack)
-        stack.append(self)
-        if tr.memory:
-            from repro.obs import memory as _memory
-            self._mem0 = _memory.sample()
-        self.t0_ns = time.perf_counter_ns()
-        return self
-
-    def __exit__(self, *exc) -> bool:
-        if self.sync:
-            _device_sync()
-        self.dur_ns = time.perf_counter_ns() - self.t0_ns
-        tr = self._tracer
-        if self._mem0 is not None:
-            from repro.obs import memory as _memory
-            m1 = _memory.sample()
-            self.attrs["rss_bytes"] = m1["rss_bytes"]
-            self.attrs["rss_delta_bytes"] = (m1["rss_bytes"]
-                                             - self._mem0["rss_bytes"])
-            if m1.get("device_bytes_in_use") is not None:
-                self.attrs["device_bytes_in_use"] = m1["device_bytes_in_use"]
-                self.attrs["device_delta_bytes"] = (
-                    m1["device_bytes_in_use"]
-                    - (self._mem0.get("device_bytes_in_use") or 0))
-        th = threading.current_thread()
-        self.tid = th.ident or 0
-        self.thread_name = th.name
-        stack = tr._stack()
-        if stack and stack[-1] is self:
-            stack.pop()
-        tr._record(self)
-        return False
-
-
-class Tracer:
-    """Span collector. ``enabled=False`` (the default) short-circuits
-    ``span`` to the shared null span."""
-
-    def __init__(self, *, enabled: bool = False, sync: bool = True,
-                 memory: bool = False):
-        self.enabled = enabled and not _DISABLED
-        self.sync = sync
-        self.memory = memory
-        self.path: Optional[str] = None
-        self._lock = threading.Lock()
-        self._spans: List[Span] = []
-        self._local = threading.local()
-        self._epoch_ns = time.perf_counter_ns()
-
-    # -- recording ---------------------------------------------------------
-    def _stack(self) -> list:
-        stack = getattr(self._local, "stack", None)
-        if stack is None:
-            stack = []
-            self._local.stack = stack
-        return stack
-
-    def _record(self, span: Span) -> None:
-        with self._lock:
-            self._spans.append(span)
-
-    def span(self, name: str, *, sync: Optional[bool] = None, **attrs):
-        """Open a span (context manager). Free when the tracer is off."""
-        if not self.enabled:
-            return NULL_SPAN
-        return Span(self, name, attrs, sync)
-
-    # -- lifecycle ---------------------------------------------------------
-    def enable(self, path: Optional[str] = None, *,
-               sync: Optional[bool] = None,
-               memory: Optional[bool] = None) -> bool:
-        """Turn the tracer on (no-op under ``REPRO_OBS_DISABLED``). ``path``
-        sets where ``export_chrome()`` writes by default. Returns whether
-        the tracer is enabled after the call."""
-        if _DISABLED:
-            return False
-        self.enabled = True
-        if path:
-            self.path = path
-        if sync is not None:
-            self.sync = sync
-        if memory is not None:
-            self.memory = memory
-        return True
-
-    def disable(self) -> None:
-        self.enabled = False
-
-    def reset(self) -> None:
-        with self._lock:
-            self._spans = []
-        self._epoch_ns = time.perf_counter_ns()
-
-    # -- introspection / export --------------------------------------------
-    def finished(self, name: Optional[str] = None) -> List[Span]:
-        """Snapshot of closed spans (optionally filtered by name)."""
-        with self._lock:
-            spans = list(self._spans)
-        if name is not None:
-            spans = [s for s in spans if s.name == name]
-        return spans
-
-    def export_chrome(self, path: Optional[str] = None) -> dict:
-        """Chrome-trace-event JSON (Perfetto / ``chrome://tracing``).
-
-        Complete ("X") events in microseconds relative to the tracer epoch;
-        per-thread metadata events give the tracks stable human names (the
-        partitioned fit's workers render as parallel ``partfit_*`` lanes).
-        Writes to ``path`` (or the path given at ``enable``) when set;
-        always returns the trace dict.
-        """
-        spans = self.finished()
-        tids: Dict[int, str] = {}
-        for s in spans:
-            tids.setdefault(s.tid, s.thread_name)
-        # stable small tids: main thread first, then by first appearance
-        tid_map = {t: i + 1 for i, t in enumerate(tids)}
-        pid = os.getpid()
-        events: List[dict] = []
-        for t, nm in tids.items():
-            events.append({"ph": "M", "pid": pid, "tid": tid_map[t],
-                           "name": "thread_name", "args": {"name": nm}})
-            events.append({"ph": "M", "pid": pid, "tid": tid_map[t],
-                           "name": "thread_sort_index",
-                           "args": {"sort_index": tid_map[t]}})
-        for s in spans:
-            events.append({
-                "ph": "X", "pid": pid, "tid": tid_map[s.tid],
-                "name": s.name,
-                "ts": (s.t0_ns - self._epoch_ns) / 1e3,
-                "dur": s.dur_ns / 1e3,
-                "args": _jsonable(s.attrs),
-            })
-        trace = {"traceEvents": events, "displayTimeUnit": "ms"}
-        path = path or self.path
-        if path:
-            d = os.path.dirname(path)
-            if d:
-                os.makedirs(d, exist_ok=True)
-            with open(path, "w") as f:
-                json.dump(trace, f)
-        return trace
-
-
-def _jsonable(attrs: Dict[str, Any]) -> Dict[str, Any]:
-    out = {}
-    for k, v in attrs.items():
-        if isinstance(v, (str, int, float, bool)) or v is None:
-            out[k] = v
-        else:
-            out[k] = repr(v)
-    return out
-
-
-#: The process tracer every pipeline layer instruments against.
-TRACER = Tracer()
-
-
-def span(name: str, *, sync: Optional[bool] = None, **attrs):
-    """Open a span on the process tracer — the one-liner used across the
-    codebase. Returns the shared null span when tracing is off."""
-    if not TRACER.enabled:
+def span(name: str, **attrs):
+    """A ``TraceAnnotation`` named ``name`` with ``attrs`` as metadata while
+    a profiler session is live; the shared no-op span otherwise."""
+    if _DISABLED or not active():
         return NULL_SPAN
-    return Span(TRACER, name, attrs, sync)
+    return TraceAnnotation(name, **attrs)
 
 
-def enable(path: Optional[str] = None, *, sync: Optional[bool] = None,
-           memory: Optional[bool] = None) -> bool:
-    """Enable the process tracer (see ``Tracer.enable``); registers an
-    atexit Chrome export when a path is given."""
-    ok = TRACER.enable(path, sync=sync, memory=memory)
-    if ok and path:
-        _register_atexit_export()
-    return ok
+def _start(path: str) -> bool:
+    """Start a session into ``path`` unless one is live; whether it did."""
+    if _DISABLED or active():
+        return False
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(path, create_perfetto_trace=True,
+                             profiler_options=options)
+    return True
 
 
-def disable() -> None:
-    TRACER.disable()
+def _stop_at_exit() -> None:
+    try:
+        jax.profiler.stop_trace()
+    except RuntimeError:        # already stopped by its owner
+        pass
 
 
-def export(path: Optional[str] = None) -> dict:
-    return TRACER.export_chrome(path)
-
-
-_ATEXIT_REGISTERED = False
-
-
-def _register_atexit_export() -> None:
-    global _ATEXIT_REGISTERED
-    if _ATEXIT_REGISTERED:
-        return
-    _ATEXIT_REGISTERED = True
-
-    def _flush():
-        if TRACER.path and TRACER.finished():
-            try:
-                TRACER.export_chrome()
-            except Exception:
-                pass
-
-    atexit.register(_flush)
+def start(path: Optional[str] = None) -> bool:
+    """Start a session into ``path`` (default ``REPRO_TRACE``) that lasts
+    until the process exits, unless one is live or no directory is given.
+    Returns whether this call started it."""
+    path = path or ENV_DIR
+    if not path or not _start(path):
+        return False
+    atexit.register(_stop_at_exit)
+    return True
 
 
 @contextlib.contextmanager
 def tracing(path: Optional[str]):
-    """Scoped tracing for one run (the ``SCRBConfig(trace=...)`` hook).
+    """Record the scope into a session written to ``path`` on exit.
 
-    ``path=None`` → passthrough. If the process tracer is *already* enabled
-    (``REPRO_TRACE``, an enclosing run, or the serving engine), this is a
-    reentrant no-op — spans land in the enclosing trace and whoever enabled
-    it exports it. Otherwise the tracer is enabled for the scope and the
-    collected trace is exported to ``path`` on exit, with the tracer
-    returned to its prior (disabled) state.
+    ``path=None`` records nothing of its own (``REPRO_TRACE``, when set,
+    starts its process-wide session here). With a session already live —
+    an enclosing ``tracing``, ``REPRO_TRACE``, the engine's, a benchmark's
+    — this is a no-op and the spans land in that session.
     """
-    if path is None or TRACER.enabled or _DISABLED:
-        yield TRACER
+    if path is None:
+        start()
+        yield
         return
-    TRACER.enable(path)
+    started = _start(path)
     try:
-        yield TRACER
+        yield
     finally:
-        try:
-            TRACER.export_chrome(path)
-        finally:
-            TRACER.disable()
-            TRACER.reset()      # scoped run: don't leak spans past export
-
-
-# REPRO_TRACE=<path>: enable process-wide tracing at import, export at exit.
-_ENV_PATH = os.environ.get("REPRO_TRACE", "")
-if _ENV_PATH and not _DISABLED:
-    enable(_ENV_PATH)
+        if started:
+            jax.profiler.stop_trace()

@@ -68,10 +68,11 @@ class EngineConfig:
     max_batch_rows: Optional[int] = None  # coalescing cap per device launch;
     # None → top bucket
     impl: Optional[str] = None            # kmeans_assign impl override
-    trace: Optional[str] = None           # Chrome-trace output path: enables
-    # process-wide repro.obs tracing at engine construction (engine.step
-    # batches emit spans) and exports the trace at process exit. None keeps
-    # tracing off; REPRO_TRACE=<path> is the env equivalent.
+    trace: Optional[str] = None           # directory for a jax.profiler
+    # trace started at engine construction and written at process exit:
+    # each step's engine.batch span and its phases on the host plane beside
+    # the device lanes. None starts none; REPRO_TRACE=<dir> is the env
+    # equivalent, and a session already live is recorded into instead.
 
     def __post_init__(self):
         if tuple(sorted(self.buckets)) != tuple(self.buckets) or \
@@ -153,7 +154,9 @@ class ClusterEngine:
     concurrent engines, e.g. a test suite's, never cross-talk), alongside a
     per-(model, mode) request-latency histogram. ``stats()`` reconstructs
     the historical dict shape from the registry — same keys, same ints —
-    plus ``latency_p50_ms``/``latency_p99_ms``; ``metrics_text()`` renders
+    plus ``latency_*`` and ``queue_wait_*`` p50/p99 in ms (the queue wait
+    runs from ``submit`` to the step that takes a request's first rows);
+    ``metrics_text()`` renders
     the registry (plus the process-global one) in Prometheus format for
     ``GET /metrics``.
     """
@@ -181,12 +184,14 @@ class ClusterEngine:
         self._latency_hist = self.registry.histogram(
             "engine_request_latency_seconds",
             "Per-request submit→complete latency.", ("model", "mode"))
+        self._queue_wait_hist = self.registry.histogram(
+            "engine_queue_wait_seconds",
+            "Per-request submit→first batch wait.", ("model", "mode"))
         self._batch_rows_hist = self.registry.histogram(
             "engine_batch_rows", "Real rows per coalesced device batch.",
             ("model",), buckets=obs_metrics.log_buckets(1.0, 2 ** 20, 2))
         self.total_compiles = 0
-        if self.config.trace:
-            obs_trace.enable(self.config.trace)
+        obs_trace.start(self.config.trace)
 
     def _bump(self, name: str, key: str, amount: int = 1) -> None:
         self._counters[key].inc(amount, model=name)
@@ -327,57 +332,78 @@ class ClusterEngine:
 
     def step(self) -> int:
         """Serve one coalesced device batch for the oldest pending
-        (model, mode) group; returns rows served (0 when idle)."""
+        (model, mode) group; returns rows served (0 when idle).
+
+        Under a profiler session the step is one ``engine.batch`` span
+        (``requests``: the requests whose first rows it takes;
+        ``queue_wait_us``: the sum of their waits from ``submit`` to the
+        step's start) holding ``engine.stage`` (copy into the staging
+        buffer), ``engine.h2d`` (``device_put``), ``engine.dispatch`` (the
+        cell call) and ``engine.readback`` (the blocking read of the
+        output). Each request's wait also feeds
+        ``engine_queue_wait_seconds``.
+        """
         if not self._pending:
             return 0
+        started_at = time.perf_counter()
         head = self._pending[0]
         name, mode = head.model, head.mode
-        cap = self.config.max_batch_rows or self.config.buckets[-1]
-        take: List[Tuple[_Request, int]] = []
-        total = 0
-        for req in self._pending:
-            if req.model != name or req.mode != mode:
-                continue
-            if total >= cap:
-                break
-            n = min(req.x.shape[0] - req.cursor, cap - total)
-            take.append((req, n))
-            total += n
-        bucket = _model.round_to_bucket(total, self.config.buckets)
-        dim = take[0][0].x.shape[1]
-        with obs_trace.span("engine.step", sync=False, model=name,
-                            mode=mode, bucket=bucket, rows=total):
+        with obs_trace.span("engine.batch", model=name, mode=mode) as batch:
+            cap = self.config.max_batch_rows or self.config.buckets[-1]
+            take: List[Tuple[_Request, int]] = []
+            total = 0
+            for req in self._pending:
+                if req.model != name or req.mode != mode:
+                    continue
+                if total >= cap:
+                    break
+                n = min(req.x.shape[0] - req.cursor, cap - total)
+                take.append((req, n))
+                total += n
+            bucket = _model.round_to_bucket(total, self.config.buckets)
+            dim = take[0][0].x.shape[1]
+            waits = [started_at - req.submitted_at for req, _ in take
+                     if req.cursor == 0]
+            for wait in waits:
+                self._queue_wait_hist.observe(wait, model=name, mode=mode)
+            batch.set_metadata(bucket=bucket, rows=total,
+                               requests=len(waits),
+                               queue_wait_us=1e6 * sum(waits))
             res = self._ensure_resident(name)
             cell = self._cell(name, bucket, mode, res, dim)
-            buf = self._ring.get(bucket, dim)
+            with obs_trace.span("engine.stage"):
+                buf = self._ring.get(bucket, dim)
+                off = 0
+                for req, n in take:
+                    buf[off:off + n] = req.x[req.cursor:req.cursor + n]
+                    off += n
+                buf[off:] = 0.0     # mask: pad rows are zeros, sliced off
+            with obs_trace.span("engine.h2d"):
+                xdev = jax.device_put(buf)
+            with obs_trace.span("engine.dispatch"):
+                if mode == "predict":
+                    out = cell(res.fm, res.dual, res.proj, res.cents, xdev)
+                else:
+                    out = cell(res.fm, res.dual, res.proj, xdev)
+            with obs_trace.span("engine.readback"):
+                out = np.asarray(out)       # blocks on the device result
+            done_at = time.perf_counter()
             off = 0
             for req, n in take:
-                buf[off:off + n] = req.x[req.cursor:req.cursor + n]
+                req.out[req.cursor:req.cursor + n] = out[off:off + n]
+                req.cursor += n
                 off += n
-            buf[off:] = 0.0                 # mask: pad rows are zeros and
-            xdev = jax.device_put(buf)      # get sliced off below
-            if mode == "predict":
-                out = cell(res.fm, res.dual, res.proj, res.cents, xdev)
-            else:
-                out = cell(res.fm, res.dual, res.proj, xdev)
-            out = np.asarray(out)           # blocks on the device result, so
-            done_at = time.perf_counter()   # the span needs no extra sync
-        off = 0
-        for req, n in take:
-            req.out[req.cursor:req.cursor + n] = out[off:off + n]
-            req.cursor += n
-            off += n
-            if req.cursor == req.x.shape[0]:
-                req.completed_at = done_at
-                self._results[req.ticket] = req
-                self._pending.remove(req)
-                self._requests_total.inc(model=name, mode=mode)
-                self._latency_hist.observe(done_at - req.submitted_at,
-                                           model=name, mode=mode)
-        self._bump(name, "rows_served", total)
-        self._bump(name, "batches")
-        self._bump(name, "padded_rows", bucket - total)
-        self._batch_rows_hist.observe(total, model=name)
+                if req.cursor == req.x.shape[0]:
+                    req.completed_at = done_at
+                    self._results[req.ticket] = req
+                    self._pending.remove(req)
+                    self._requests_total.inc(model=name, mode=mode)
+                    self._latency_hist.observe(done_at - req.submitted_at,
+                                               model=name, mode=mode)
+            self._bump(name, "rows_served", total)
+            self._bump(name, "batches")
+            self._bump(name, "padded_rows", bucket - total)
+            self._batch_rows_hist.observe(total, model=name)
         return total
 
     def drain(self) -> int:
@@ -441,11 +467,13 @@ class ClusterEngine:
         for m in self._models:
             d = self._model_stat_dict(m)
             for mode in MODES:
-                p50 = self._latency_hist.quantile(0.5, model=m, mode=mode)
-                p99 = self._latency_hist.quantile(0.99, model=m, mode=mode)
-                if p50 is not None:
-                    d[f"latency_{mode}_p50_ms"] = p50 * 1e3
-                    d[f"latency_{mode}_p99_ms"] = p99 * 1e3
+                for key, hist in (("latency", self._latency_hist),
+                                  ("queue_wait", self._queue_wait_hist)):
+                    p50 = hist.quantile(0.5, model=m, mode=mode)
+                    p99 = hist.quantile(0.99, model=m, mode=mode)
+                    if p50 is not None:
+                        d[f"{key}_{mode}_p50_ms"] = p50 * 1e3
+                        d[f"{key}_{mode}_p99_ms"] = p99 * 1e3
             per[m] = d
         return {
             "models": per,

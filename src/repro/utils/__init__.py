@@ -35,8 +35,8 @@ class StageTimer:
     so timings are honest under async dispatch.
 
     Since the observability subsystem landed this is a compatibility shim:
-    each ``stage`` additionally opens a ``repro.obs.trace`` span (``sync``
-    left to the tracer default) and feeds the ``repro_stage_seconds``
+    each ``stage`` additionally opens a ``repro.obs.trace`` span (recorded
+    while a profiler session is live) and feeds the ``repro_stage_seconds``
     histogram, but ``self.times`` is still populated from the timer's own
     ``perf_counter`` pair so the `{stage: seconds}` contract — and
     ``FitResult.timings`` built on it — is preserved bit-for-bit.
@@ -126,9 +126,8 @@ def prefetch_to_device(
     streamed rather than a closed-form estimate. Every upload also feeds
     the process metrics registry (``repro_prefetch_items_total`` /
     ``repro_prefetch_bytes_total``, scrapable at ``GET /metrics``) and,
-    when tracing is on, an ``h2d`` span per item (``sync=False`` — the span
-    times the *issue*, on purpose: syncing here would serialize the double
-    buffering this generator exists to provide).
+    under a profiler session, an ``h2d`` span per item (it covers the
+    *issue*; the copy itself shows on the device lanes).
 
     .. deprecated:: the ``stats=`` keyword is the pre-observability name of
        ``measure=`` and now emits a ``DeprecationWarning``; it behaves
@@ -158,7 +157,7 @@ def prefetch_to_device(
             measure["items"] = measure.get("items", 0) + 1
         _PREFETCH_ITEMS.inc()
         _PREFETCH_BYTES.inc(nbytes)
-        with _trace.span("h2d", sync=False, bytes=nbytes):
+        with _trace.span("h2d", bytes=nbytes):
             return jax.tree_util.tree_map(jax.device_put, t)
 
     it = iter(items)
