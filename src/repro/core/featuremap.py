@@ -21,9 +21,10 @@ Protocol (all maps are frozen dataclasses registered as pytrees, so a
                                   feature matrices (N, m).
   ``n_features``                  total feature columns D.
 
-plus the out-of-sample trio used by ``repro.core.model.SCRBModel`` —
-``oos_degrees`` (degree of a *new* point against the fitted training graph,
-from the O(D) degree dual), ``oos_rowscale``, and ``project`` (Ẑ_new · M).
+plus the out-of-sample projection used by ``repro.core.model.SCRBModel``,
+``oos_project`` (Ẑ_new · M: a *new* point's features, normalized by its
+degree against the fitted training graph, read from the O(D) degree dual,
+times the fitted (D, K) projection M).
 
 Registered implementations (``FEATURE_MAPS``):
 
@@ -49,7 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import graph, rb, rff, streaming
+from repro.core import rb, rff, streaming
 from repro.core.nystrom import pairwise_kernel
 from repro.kernels import ops
 from repro.utils import fold_key, prefetch_to_device
@@ -67,9 +68,8 @@ class FeatureMap(Protocol):
     @property
     def n_features(self) -> int: ...
     # out-of-sample extension (jit-able; ``dual`` is the fitted degree dual)
-    def oos_degrees(self, feats: jax.Array, dual: jax.Array) -> jax.Array: ...
-    def oos_rowscale(self, deg: jax.Array, *, laplacian: bool) -> jax.Array: ...
-    def project(self, feats, rowscale, m: jax.Array) -> jax.Array: ...
+    def oos_project(self, feats: jax.Array, dual: jax.Array, m: jax.Array,
+                    *, laplacian: bool) -> jax.Array: ...
 
 
 def _chunk_list(x) -> list:
@@ -119,20 +119,35 @@ class RBMap:
     def n_features(self) -> int:
         return self.params.n_features
 
-    def oos_degrees(self, feats: jax.Array, dual: jax.Array) -> jax.Array:
-        """deg(x) = (1/R) Σ_g counts[idx_g] — the fitted bin occupancies
-        evaluated at the new point's bins (Eq. 6, one-sided; the same
-        row-local reduction the streaming degree pass uses)."""
-        return graph.degrees_from_counts(feats, dual)
+    def oos_project(self, feats: jax.Array, dual: jax.Array, m: jax.Array,
+                    *, laplacian: bool) -> jax.Array:
+        """Ẑ_new · M (N, K) for ELL rows ``feats``.
 
-    def oos_rowscale(self, deg: jax.Array, *, laplacian: bool) -> jax.Array:
-        inv_sqrt_r = 1.0 / jnp.sqrt(jnp.float32(self.n_grids))
+        Under Laplacian normalization each row is scaled by 1/√(R·deg(x)),
+        with deg(x) = (1/R) Σ_g dual[idx_g] — the fitted bin occupancies at
+        the new point's bins (Eq. 6, one-sided). That is the projection's
+        own sum over the same bins with ``dual`` in place of a column of M,
+        so it rides in the projection's gather as one more column
+        (``_sums_and_degrees``) instead of a gather of its own. Without
+        normalization every row is scaled by the constant 1/√R.
+        """
+        r = self.n_grids
         if not laplacian:
-            return jnp.full_like(deg, inv_sqrt_r)
-        return 1.0 / jnp.sqrt(self.n_grids * jnp.maximum(deg, 1e-8))
+            scale = jnp.full((feats.shape[0],), 1.0 / jnp.sqrt(jnp.float32(r)))
+            return ops.z_matmul(feats, m, scale, d_g=self.d_g, impl=self.impl)
+        sums, deg = self._sums_and_degrees(feats, dual, m)
+        rowscale = 1.0 / jnp.sqrt(r * jnp.maximum(deg, 1e-8))
+        return sums * rowscale[:, None]
 
-    def project(self, feats, rowscale, m: jax.Array) -> jax.Array:
-        return ops.z_matmul(feats, m, rowscale, d_g=self.d_g, impl=self.impl)
+    def _sums_and_degrees(self, feats, dual, m):
+        """(Σ_g M[idx_g] (N, K), deg (N,)) from one ``z_matmul`` over
+        [M | dual]. ``dual`` holds integer counts, and the degree column's
+        partial sums are integers below 2^24, so deg is exact on every
+        route, as ``graph.degrees_from_counts`` is."""
+        mv = jnp.concatenate([m, dual[:, None].astype(m.dtype)], axis=1)
+        unit = jnp.ones((feats.shape[0],), jnp.float32)
+        s = ops.z_matmul(feats, mv, unit, d_g=self.d_g, impl=self.impl)
+        return s[:, :-1], s[:, -1] / self.n_grids
 
     # -- (de)serialization / pytree ----------------------------------------
     def meta_dict(self) -> dict:
@@ -170,16 +185,13 @@ class RBMap:
 class _DenseOOS:
     kind = "dense"
 
-    def oos_degrees(self, feats: jax.Array, dual: jax.Array) -> jax.Array:
-        """deg(x) = φ(x) · (Φᵀ1) — kernel-degree of a new point vs train."""
-        return feats @ dual
-
-    def oos_rowscale(self, deg: jax.Array, *, laplacian: bool) -> jax.Array:
-        if not laplacian:
-            return jnp.ones_like(deg)
-        return 1.0 / jnp.sqrt(jnp.maximum(deg, 1e-8))
-
-    def project(self, feats, rowscale, m: jax.Array) -> jax.Array:
+    def oos_project(self, feats: jax.Array, dual: jax.Array, m: jax.Array,
+                    *, laplacian: bool) -> jax.Array:
+        """(D̂^{-1/2} Φ_new) · M, with deg(x) = φ(x) · (Φᵀ1) — the kernel
+        degree of a new point against the training rows."""
+        deg = feats @ dual
+        rowscale = 1.0 / jnp.sqrt(jnp.maximum(deg, 1e-8)) if laplacian \
+            else jnp.ones_like(deg)
         return (feats * rowscale[:, None]) @ m
 
 
@@ -402,6 +414,12 @@ def make_feature_map(name: str, *, rank: int, sigma: float,
 def from_config(cfg, impl: str = "auto") -> RBMap:
     """The default stage-1 map of an ``SCRBConfig``: Random Binning."""
     return RBMap(n_grids=cfg.n_grids, sigma=cfg.sigma, d_g=cfg.d_g, impl=impl)
+
+
+def fused_degree(fm, *, laplacian: bool) -> bool:
+    """Whether ``fm.oos_project`` reads each row's degree from the
+    projection's own gather: ELL maps under Laplacian normalization."""
+    return laplacian and fm.kind == "ell"
 
 
 def load_fitted(meta: dict, arrays: dict) -> FeatureMap:

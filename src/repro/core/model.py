@@ -79,9 +79,8 @@ def _oos_embed_impl(fm, dual, proj, x, *, laplacian: bool) -> jax.Array:
     function so callers (the serving engine) can AOT-compile it per batch
     bucket with their own donation policy."""
     feats = fm.transform(jnp.asarray(x, jnp.float32))
-    deg = fm.oos_degrees(feats, dual)
-    scale = fm.oos_rowscale(deg, laplacian=laplacian)
-    return row_normalize(fm.project(feats, scale, proj))
+    return row_normalize(fm.oos_project(feats, dual, proj,
+                                        laplacian=laplacian))
 
 
 def _oos_predict_impl(fm, dual, proj, cents, x, *, laplacian: bool,

@@ -45,7 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import model as _model
+from repro.core import featuremap, model as _model
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 
@@ -184,6 +184,10 @@ class ClusterEngine:
         self._latency_hist = self.registry.histogram(
             "engine_request_latency_seconds",
             "Per-request submit→complete latency.", ("model", "mode"))
+        self._fused_batches = self.registry.counter(
+            "engine_fused_degree_batches_total",
+            "Batches whose cell read the degree from the projection's "
+            "gather.", ("model", "mode"))
         self._queue_wait_hist = self.registry.histogram(
             "engine_queue_wait_seconds",
             "Per-request submit→first batch wait.", ("model", "mode"))
@@ -215,6 +219,8 @@ class ClusterEngine:
         self._models[name] = mdl
         for key in STAT_KEYS:       # materialize zeroed series so the model
             self._counters[key].inc(0, model=name)   # shows in /metrics now
+        for mode in MODES:
+            self._fused_batches.inc(0, model=name, mode=mode)
         return mdl
 
     def _ensure_resident(self, name: str) -> _Resident:
@@ -402,6 +408,10 @@ class ClusterEngine:
                                                model=name, mode=mode)
             self._bump(name, "rows_served", total)
             self._bump(name, "batches")
+            mdl = self._models[name]
+            if featuremap.fused_degree(mdl.feature_map,
+                                       laplacian=mdl.laplacian_normalize):
+                self._fused_batches.inc(model=name, mode=mode)
             self._bump(name, "padded_rows", bucket - total)
             self._batch_rows_hist.observe(total, model=name)
         return total
@@ -445,11 +455,17 @@ class ClusterEngine:
 
     def _model_stat_dict(self, name: str) -> Dict[str, int]:
         """One model's historical 8-key stats dict, reconstructed from the
-        registry counters (same keys, same ints as the pre-registry dicts)."""
+        registry counters (same keys, same ints as the pre-registry dicts),
+        plus ``fused_degree_batches`` over both modes: its ratio to
+        ``batches`` is the share of batches whose degree came from the
+        projection's gather."""
         if name not in self._models:
             raise KeyError(name)
-        return {key: int(self._counters[key].get(model=name))
-                for key in STAT_KEYS}
+        d = {key: int(self._counters[key].get(model=name))
+             for key in STAT_KEYS}
+        d["fused_degree_batches"] = int(sum(
+            self._fused_batches.get(model=name, mode=mode) for mode in MODES))
+        return d
 
     def latency_quantiles(self, name: str, mode: str = "predict",
                           *, qs: Tuple[float, ...] = (0.5, 0.99)
@@ -485,6 +501,8 @@ class ClusterEngine:
             "pending": len(self._pending),
             "rows_served": sum(s["rows_served"] for s in per.values()),
             "batches": sum(s["batches"] for s in per.values()),
+            "fused_degree_batches": sum(s["fused_degree_batches"]
+                                        for s in per.values()),
             "padded_rows": sum(s["padded_rows"] for s in per.values()),
             "evictions": sum(s["evictions"] for s in per.values()),
         }

@@ -4,14 +4,18 @@ O(D·K)-state guarantee of ``repro.core.model.SCRBModel``."""
 import json
 import os
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import SCRBConfig, SCRBModel, metrics, sc_rb
+from repro.core import SCRBConfig, SCRBModel, graph, metrics, sc_rb
 from repro.core.executor import ExecutionPlan
+from repro.core.featuremap import RBMap
+from repro.core.kmeans import row_normalize
 from repro.core.model import BUCKET_GRID, round_to_bucket
 from repro.data.synthetic import make_blobs
+from repro.kernels import ops
 
 DATA_DIR = os.path.join(os.path.dirname(__file__), "data")
 
@@ -147,6 +151,60 @@ def test_bucket_padded_predict_bit_identical(blobs):
     # ragged single chunk smaller than any bucket
     np.testing.assert_array_equal(model.predict(x[:17], batch_size=64),
                                   want[:17])
+
+
+def _three_step_oos(fm, feats, dual, m, laplacian):
+    """The out-of-sample projection as three separate steps: the degree by
+    its own gather of the bin counts, the row scale, then the projection."""
+    deg = graph.degrees_from_counts(feats, dual)
+    if laplacian:
+        scale = 1.0 / jnp.sqrt(fm.n_grids * jnp.maximum(deg, 1e-8))
+    else:
+        scale = jnp.full_like(deg, 1.0 / jnp.sqrt(jnp.float32(fm.n_grids)))
+    return ops.z_matmul(feats, m, scale, d_g=fm.d_g, impl=fm.impl), deg
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("laplacian", [True, False], ids=["lap", "plain"])
+@pytest.mark.parametrize("k", [1, 10, 15, 16])
+def test_oos_project_fused_degree_matches_take_sum(k, laplacian, impl):
+    """``RBMap.oos_project`` reads each row's degree from the projection's
+    gather ([M | dual] in one ``z_matmul``). Against the three-step formula
+    it gives exactly the take-sum degree, the same embedding bit for bit on
+    the same route (float32 rounding across routes) and the same labels —
+    K + 1 inside the 8-row pad (K = 10, 15) or one tile past it (K = 16),
+    with a row whose training bins were all empty (degree 0, clamped)."""
+    r, d_g, n = 8, 32, 40
+    rng = np.random.default_rng(k)
+    feats = jnp.asarray(np.arange(r) * d_g
+                        + rng.integers(1, d_g, (n, r)), jnp.int32)
+    feats = feats.at[0].set(jnp.arange(r) * d_g)        # bin 0 of each grid
+    counts = rng.integers(0, 70_000, r * d_g)
+    counts[::d_g] = 0                                   # ... is empty
+    dual = jnp.asarray(counts, jnp.float32)
+    m = jnp.asarray(rng.normal(size=(r * d_g, k)), jnp.float32)
+    fm = RBMap(n_grids=r, sigma=1.0, d_g=d_g, impl=impl)
+
+    fused = jax.jit(fm.oos_project, static_argnames="laplacian")
+    old = jax.jit(_three_step_oos, static_argnums=(0, 4))
+    got = fused(feats, dual, m, laplacian=laplacian)
+    want, want_deg = old(fm, feats, dual, m, laplacian)
+    deg = jax.jit(fm._sums_and_degrees)(feats, dual, m)[1]
+    assert float(want_deg[0]) == 0.0
+    np.testing.assert_array_equal(deg, want_deg)
+    np.testing.assert_array_equal(got, want)
+
+    emb = row_normalize(got)
+    if impl != "xla":
+        xla = RBMap(n_grids=r, sigma=1.0, d_g=d_g, impl="xla")
+        np.testing.assert_allclose(
+            emb, row_normalize(xla.oos_project(feats, dual, m,
+                                               laplacian=laplacian)),
+            rtol=1e-6, atol=1e-6)
+    cents = row_normalize(jnp.asarray(rng.normal(size=(5, k)), jnp.float32))
+    np.testing.assert_array_equal(
+        ops.kmeans_assign(emb, cents, impl=impl)[0],
+        ops.kmeans_assign(row_normalize(want), cents, impl=impl)[0])
 
 
 def test_load_v1_artifact_compat():

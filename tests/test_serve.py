@@ -194,6 +194,36 @@ def test_engine_coalesces_and_splits(fitted):
     np.testing.assert_array_equal(eng.take(t).values, mdl.predict(big))
 
 
+def test_engine_fused_degree_counter(fitted):
+    """Every batch of an RB model reads its degree from the projection's
+    gather, in both modes; a dense-map (RFF) model's batches never do."""
+    from repro.core import featuremap
+    from repro.core.executor import ExecutionPlan
+    _, x = fitted["blobs"]
+    rff = SCRBModel.fit(x, SCRBConfig(
+        n_clusters=4, n_grids=16, sigma=1.5,
+        kmeans_replicates=1, seed=0), plan=ExecutionPlan(
+            feature_map=featuremap.make_feature_map("rff", rank=64,
+                                                    sigma=1.5)))
+    eng = _engine(fitted)
+    eng.load_model("rff", rff)
+    eng.predict("blobs", x[:40])
+    eng.transform("blobs", x[:100])
+    eng.predict("blobs", x[:200])                 # > top bucket: two batches
+    np.testing.assert_array_equal(eng.predict("rff", x[:50]),
+                                  rff.predict(x[:50]))
+    rb, dense = eng.stats("blobs"), eng.stats("rff")
+    assert rb["fused_degree_batches"] == rb["batches"] == 4
+    assert dense["batches"] == 1 and dense["fused_degree_batches"] == 0
+    assert eng.stats()["fused_degree_batches"] == 4
+    text = eng.metrics_text()
+    for model, mode, value in (("blobs", "predict", 3),
+                               ("blobs", "transform", 1),
+                               ("rff", "predict", 0), ("rff", "transform", 0)):
+        assert (f'engine_fused_degree_batches_total{{model="{model}",'
+                f'mode="{mode}"}} {value}') in text
+
+
 def test_engine_edge_requests(fitted):
     eng = _engine(fitted)
     # empty request completes without device work

@@ -63,14 +63,29 @@ def test_rb_binning_compiles(one_chip, chip_route, n, d):
                     ((R, d), jnp.uint32), ((R,), jnp.uint32)) == 1
 
 
-@pytest.mark.parametrize("d_g", [256, 4096])
-@pytest.mark.parametrize("product", ["z", "zt", "gram"])
+# "oos" is the serving projection with the degree fused into its gather
+# (``RBMap.oos_project``), at the serve cells' top bucket and hash widths
+# (poker d_g 512, mnist d_g 2048) and K = 10, so the kernel gathers K + 1
+# columns.
+@pytest.mark.parametrize("product,d_g", [
+    (p, w) for p in ("z", "zt", "gram") for w in (256, 4096)] + [
+    ("oos", 512), ("oos", 2048)], ids=lambda v: str(v))
 def test_ell_products_compile(one_chip, chip_route, product, d_g):
     n = POKER[0]
     d = R * d_g
     idx = ((n, R), jnp.int32)
     scale = ((n,), jnp.float32)
-    if product == "z":
+    if product == "oos":
+        from repro.core.featuremap import RBMap
+        fm = RBMap(n_grids=R, sigma=1.0, d_g=d_g, impl="pallas")
+        args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in
+                (((4096, R), jnp.int32), ((d,), jnp.float32),
+                 ((d, 10), jnp.float32))]
+        text = jax.jit(lambda i, dual, m: fm.oos_project(
+            i, dual, m, laplacian=True)).lower(*args).compile().as_text()
+        assert " gather(" not in text     # no XLA gather of the degree dual
+        count = text.count('custom_call_target="tpu_custom_call"')
+    elif product == "z":
         count = _compile(one_chip, lambda i, v, s: ops.z_matmul(
             i, v, s, d_g=d_g, impl="pallas"), idx, ((d, K), jnp.float32),
             scale)
