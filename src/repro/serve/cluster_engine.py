@@ -54,7 +54,8 @@ MODES = ("predict", "transform")
 #: The per-model counters behind ``stats()`` — one ``engine_<key>_total``
 #: counter per key on the engine's private registry.
 STAT_KEYS = ("compiles", "cache_hits", "resident_hits", "resident_misses",
-             "evictions", "rows_served", "batches", "padded_rows")
+             "evictions", "rows_served", "batches", "padded_rows",
+             "model_switches")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -172,6 +173,7 @@ class ClusterEngine:
         self._pending: "collections.deque[_Request]" = collections.deque()
         self._results: Dict[int, _Request] = {}
         self._tickets = itertools.count()
+        self._last_model: Optional[str] = None   # model of the latest step
         self.registry = obs_metrics.MetricsRegistry()
         self._counters: Dict[str, obs_metrics.Counter] = {
             key: self.registry.counter(
@@ -343,10 +345,12 @@ class ClusterEngine:
         Under a profiler session the step is one ``engine.batch`` span
         (``requests``: the requests whose first rows it takes;
         ``queue_wait_us``: the sum of their waits from ``submit`` to the
-        step's start) holding ``engine.stage`` (copy into the staging
-        buffer), ``engine.h2d`` (``device_put``), ``engine.dispatch`` (the
-        cell call) and ``engine.readback`` (the blocking read of the
-        output). Each request's wait also feeds
+        step's start; ``switched``: 1 when its model differs from the
+        previous step's, which ``engine_model_switches_total`` counts under
+        the model switched to) holding ``engine.stage`` (copy into the
+        staging buffer), ``engine.h2d`` (``device_put``),
+        ``engine.dispatch`` (the cell call) and ``engine.readback`` (the
+        blocking read of the output). Each request's wait also feeds
         ``engine_queue_wait_seconds``.
         """
         if not self._pending:
@@ -372,9 +376,12 @@ class ClusterEngine:
                      if req.cursor == 0]
             for wait in waits:
                 self._queue_wait_hist.observe(wait, model=name, mode=mode)
+            switched = self._last_model not in (None, name)
+            self._last_model = name
             batch.set_metadata(bucket=bucket, rows=total,
                                requests=len(waits),
-                               queue_wait_us=1e6 * sum(waits))
+                               queue_wait_us=1e6 * sum(waits),
+                               switched=int(switched))
             res = self._ensure_resident(name)
             cell = self._cell(name, bucket, mode, res, dim)
             with obs_trace.span("engine.stage"):
@@ -408,6 +415,7 @@ class ClusterEngine:
                                                model=name, mode=mode)
             self._bump(name, "rows_served", total)
             self._bump(name, "batches")
+            self._bump(name, "model_switches", int(switched))
             mdl = self._models[name]
             if featuremap.fused_degree(mdl.feature_map,
                                        laplacian=mdl.laplacian_normalize):
@@ -454,8 +462,8 @@ class ClusterEngine:
         return tuple(self._resident)
 
     def _model_stat_dict(self, name: str) -> Dict[str, int]:
-        """One model's historical 8-key stats dict, reconstructed from the
-        registry counters (same keys, same ints as the pre-registry dicts),
+        """One model's ``STAT_KEYS`` dict, read from the registry counters
+        (``model_switches``: its steps that followed another model's),
         plus ``fused_degree_batches`` over both modes: its ratio to
         ``batches`` is the share of batches whose degree came from the
         projection's gather."""
@@ -505,6 +513,7 @@ class ClusterEngine:
                                         for s in per.values()),
             "padded_rows": sum(s["padded_rows"] for s in per.values()),
             "evictions": sum(s["evictions"] for s in per.values()),
+            "model_switches": sum(s["model_switches"] for s in per.values()),
         }
 
     def metrics_text(self) -> str:

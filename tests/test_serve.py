@@ -1,7 +1,10 @@
 """Serving engine tests: the LM generation loop (sampling, EOS, cache
 reuse) and the cluster predict engine (bucketed jit cache, coalescing,
 LRU, hot-swap, HTTP front end)."""
+import glob
+import importlib.util
 import json
+import os
 import urllib.request
 
 import jax
@@ -9,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import SCRBConfig, SCRBModel
+from repro.core import SCRBConfig, SCRBModel, rb
 from repro.data.synthetic import make_blobs, make_rings
 from repro.models import transformer as T
 from repro.models.config import ModelConfig, dense_segments
@@ -222,6 +225,95 @@ def test_engine_fused_degree_counter(fitted):
                                ("rff", "predict", 0), ("rff", "transform", 0)):
         assert (f'engine_fused_degree_batches_total{{model="{model}",'
                 f'mode="{mode}"}} {value}') in text
+
+
+@pytest.fixture(scope="module")
+def zoo():
+    """Three tiny fitted models of different d and K."""
+    out = {}
+    for name, d, k, seed in (("d4k3", 4, 3, 10), ("d16k7", 16, 7, 11),
+                             ("d10k10", 10, 10, 12)):
+        x, _ = make_blobs(400, d, k, seed=seed)
+        out[name] = (SCRBModel.fit(x, SCRBConfig(
+            n_clusters=k, n_grids=16, sigma=float(rb.suggest_sigma(x)),
+            d_g=128, solver_tol=1e-2, kmeans_replicates=1, seed=seed)), x)
+    return out
+
+
+def _reference_labels(mdl, x):
+    """Nearest centroid of the plain reference's out-of-sample embedding
+    (``bench/reference.py``, which imports nothing of the program; loaded
+    from its file, so that neither ``sys.path`` nor ``sys.modules`` keeps
+    it)."""
+    spec = importlib.util.spec_from_file_location(
+        "_bench_reference", os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "bench", "reference.py"))
+    reference = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reference)
+    model = {"grids": mdl.feature_map.state_dict(),
+             "d_g": mdl.feature_map.d_g, "dual": mdl.degree_dual,
+             "right_vectors": mdl.right_vectors,
+             "singular_values": mdl.singular_values}
+    emb = reference.embed_new(x, model)
+    return np.asarray(reference.sq_dists(
+        emb, jnp.asarray(mdl.centroids))).argmin(1)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_engine_interleaved_models(zoo, tmp_path, seed):
+    """Requests for three resident models of different d and K, submitted
+    in a seeded order with sizes across the bucket edges and served a few
+    steps at a time: every label is the model's own (its ``predict`` alone
+    and the reference's nearest centroid), nothing is evicted, and
+    ``model_switches`` and each ``engine.batch`` span's ``switched`` count
+    the model changes between consecutive batches."""
+    from jax.profiler import ProfileData
+    rng = np.random.default_rng(seed)
+    names = list(zoo)
+    eng = ClusterEngine(EngineConfig(buckets=BUCKETS))
+    for name in names:
+        eng.load_model(name, zoo[name][0])
+        eng.warmup(name)
+    sizes = (1, 31, 32, 33, 63, 64, 65, 127, 128, 129, 200)
+    reqs = [(names[i], int(rng.choice(sizes)),
+             int(rng.integers(0, 150))) for i in rng.integers(0, 3, 40)]
+    tickets = []
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for name, n, off in reqs:
+            tickets.append(eng.submit(name, zoo[name][1][off:off + n]))
+            for _ in range(int(rng.integers(0, 3))):
+                eng.step()
+        eng.drain()
+    finally:
+        jax.profiler.stop_trace()
+    served = {name: ([], []) for name in names}
+    for (name, n, off), t in zip(reqs, tickets):
+        served[name][0].append(zoo[name][1][off:off + n])
+        served[name][1].append(eng.take(t).values)
+    for name, (rows, labels) in served.items():
+        mdl = zoo[name][0]
+        rows, labels = np.concatenate(rows), np.concatenate(labels)
+        np.testing.assert_array_equal(labels, mdl.predict(rows))
+        np.testing.assert_array_equal(labels, _reference_labels(mdl, rows))
+    path, = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    batches = sorted((int(e.start_ns), dict(e.stats))
+                     for plane in ProfileData.from_file(path).planes
+                     for line in plane.lines for e in line.events
+                     if e.name == "engine.batch")
+    order = [attrs["model"] for _, attrs in batches]
+    changes = [int(a != b) for a, b in zip(order, order[1:])]
+    assert len(order) == eng.stats()["batches"]
+    assert [attrs["switched"] for _, attrs in batches] == [0] + changes
+    s = eng.stats()
+    assert s["model_switches"] == sum(changes) > 0
+    for name in names:         # counted under the model switched to
+        assert s["models"][name]["model_switches"] == sum(
+            c for c, m in zip(changes, order[1:]) if m == name)
+    assert s["evictions"] == 0 and len(s["resident"]) == 3
+    assert (f'engine_model_switches_total{{model="{names[0]}"}} '
+            f'{s["models"][names[0]]["model_switches"]}') in eng.metrics_text()
 
 
 def test_engine_edge_requests(fitted):

@@ -15,6 +15,8 @@ from repro.kernels import ops
 
 POKER = (1_025_010, 10)      # (N, d) of paper Table 1 poker
 MNIST = (70_000, 780)        # the widest Table 1 input
+COVTYPE = (581_012, 54)      # Table 1 covtype-mult (K 7)
+ACOUSTIC = (98_528, 50)      # Table 1 acoustic (K 3)
 R, K = 256, 16
 
 
@@ -54,7 +56,8 @@ def _compile(sharding, fn, *shapes):
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
 
-@pytest.mark.parametrize("n,d", [POKER, MNIST], ids=["poker", "mnist"])
+@pytest.mark.parametrize("n,d", [POKER, MNIST, COVTYPE, ACOUSTIC],
+                         ids=["poker", "mnist", "covtype-mult", "acoustic"])
 def test_rb_binning_compiles(one_chip, chip_route, n, d):
     def fn(x, w, b, a, c):
         return ops.rb_binning(x, w, b, a, c, d_g=4096, impl="pallas")
@@ -76,15 +79,7 @@ def test_ell_products_compile(one_chip, chip_route, product, d_g):
     idx = ((n, R), jnp.int32)
     scale = ((n,), jnp.float32)
     if product == "oos":
-        from repro.core.featuremap import RBMap
-        fm = RBMap(n_grids=R, sigma=1.0, d_g=d_g, impl="pallas")
-        args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in
-                (((4096, R), jnp.int32), ((d,), jnp.float32),
-                 ((d, 10), jnp.float32))]
-        text = jax.jit(lambda i, dual, m: fm.oos_project(
-            i, dual, m, laplacian=True)).lower(*args).compile().as_text()
-        assert " gather(" not in text     # no XLA gather of the degree dual
-        count = text.count('custom_call_target="tpu_custom_call"')
+        count = _compile_oos(one_chip, d_g, 10)
     elif product == "z":
         count = _compile(one_chip, lambda i, v, s: ops.z_matmul(
             i, v, s, d_g=d_g, impl="pallas"), idx, ((d, K), jnp.float32),
@@ -103,6 +98,29 @@ def test_ell_products_compile(one_chip, chip_route, product, d_g):
     assert count == want
 
 
+def _compile_oos(sharding, d_g: int, k: int) -> int:
+    """The fused serving projection over [M | dual] (K + 1 columns) at the
+    top bucket; returns its kernel count."""
+    from repro.core.featuremap import RBMap
+    fm = RBMap(n_grids=R, sigma=1.0, d_g=d_g, impl="pallas")
+    d = R * d_g
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding) for s, dt in
+            (((4096, R), jnp.int32), ((d,), jnp.float32),
+             ((d, k), jnp.float32))]
+    text = jax.jit(lambda i, dual, m: fm.oos_project(
+        i, dual, m, laplacian=True)).lower(*args).compile().as_text()
+    assert " gather(" not in text     # no XLA gather of the degree dual
+    return text.count('custom_call_target="tpu_custom_call"')
+
+
+# the zoo's other members: K + 1 = 4 (acoustic, d_g 4096) is part of one
+# 8-row tile, K + 1 = 8 (covtype-mult, d_g 2048) exactly one
+@pytest.mark.parametrize("k,d_g", [(3, 4096), (7, 2048)],
+                         ids=["acoustic", "covtype-mult"])
+def test_fused_projection_compiles_at_small_k(one_chip, chip_route, k, d_g):
+    assert _compile_oos(one_chip, d_g, k) == 1
+
+
 def test_fused_gram_kernel_compiles_at_vmem_budget(one_chip):
     """The largest fused intermediate the budget admits at R = 256, K = 16
     (d_g = 512, 8 MiB) fits the compiler's scoped VMEM."""
@@ -117,7 +135,10 @@ def test_fused_gram_kernel_compiles_at_vmem_budget(one_chip):
         ((1, n), jnp.float32)) == 1
 
 
-def test_kmeans_assign_compiles(one_chip, chip_route):
+@pytest.mark.parametrize("n,k", [(POKER[0], 10), (COVTYPE[0], 7),
+                                 (ACOUSTIC[0], 3)],
+                         ids=["poker", "covtype-mult", "acoustic"])
+def test_kmeans_assign_compiles(one_chip, chip_route, n, k):
     assert _compile(one_chip, lambda x, c: ops.kmeans_assign(
-        x, c, impl="pallas"), ((POKER[0], 10), jnp.float32),
-        ((10, 10), jnp.float32)) == 1
+        x, c, impl="pallas"), ((n, k), jnp.float32),
+        ((k, k), jnp.float32)) == 1
