@@ -25,9 +25,15 @@ tile ``(dc, block_n)`` is an iota compare against that row broadcast over
 sublanes. Every block's last two dimensions are either full or aligned to
 the (8, 128) tiling, and the loop over the grids of a block is a
 ``lax.fori_loop`` with dynamic sublane / leading-dimension indexing, so the
-kernel body does not grow with R or d_g. Matmuls run at
-``Precision.HIGHEST`` (the one-hot is exact; the dense factor keeps its f32
-bits), matching the gather/segment-sum XLA route to f32 rounding.
+kernel body does not grow with R or d_g.
+
+The gather ``Ẑ·V`` is one bf16 MXU pass per one-hot tile: the one-hot is
+exact in bf16, and an f32 factor chunk enters as its three exact bf16 terms
+(``bf16_terms``) stacked on the row axis, so the three row blocks of the
+product sum back to the f32 gather bit for bit. The scatter ``Ẑᵀ·U`` and
+the fused Gram kernel run at ``Precision.HIGHEST``: their sums of f32
+products would not stay exact under a split. Both match the
+gather/segment-sum XLA route to f32 rounding.
 
 ``ops.py`` builds these layouts from the natural (N, R) / (N, K) / (D, K)
 arrays and slices the padding back off.
@@ -72,6 +78,39 @@ def _onehot_t(row, base, dc, dtype):
     return (iota == row - base).astype(dtype)
 
 
+def bf16_terms(v):
+    """f32 ``v`` as three bf16 terms, each held in f32: hi = bf16(v),
+    mid = bf16(v − hi), lo = bf16(v − hi − mid). Each remainder is exact in
+    f32 and three 8-bit significands cover f32's 24 bits, so
+    ``(hi + mid) + lo == v + 0`` bit for bit: only a zero's sign is lost,
+    as it is in any one-hot product, whose other terms are +0."""
+    def bf16(x):
+        return x.astype(jnp.bfloat16).astype(jnp.float32)
+    hi = bf16(v)
+    mid = bf16(v - hi)
+    return hi, mid, bf16(v - hi - mid)
+
+
+def _gather_chunk(v, onehot):
+    """(K, bn) f32 = v · onehot, exactly, in one bf16 MXU pass. An f32
+    ``v`` enters as its terms [hi; mid; lo] stacked on the row axis (padded
+    to bf16's 16-row sublane tile); each product column picks one value of
+    each term, so the three row blocks sum back to ``v``'s gathered values.
+    The pass is pinned to DEFAULT precision: under a caller's
+    ``default_matmul_precision("highest")`` (the fit's) it would otherwise
+    run as several."""
+    dot = functools.partial(jax.lax.dot, precision=jax.lax.Precision.DEFAULT,
+                            preferred_element_type=jnp.float32)
+    if v.dtype == jnp.bfloat16:
+        return dot(v, onehot)
+    k = v.shape[0]
+    terms = [*bf16_terms(v.astype(jnp.float32))]
+    if 3 * k % 16:
+        terms.append(jnp.zeros((-3 * k % 16, v.shape[1]), jnp.float32))
+    y = dot(jnp.concatenate(terms).astype(jnp.bfloat16), onehot)
+    return (y[:k] + y[k:2 * k]) + y[2 * k:3 * k]
+
+
 def _z_matmul_kernel(idx_ref, v_ref, s_ref, y_ref, *, d_g, dc, block_r):
     """y_t[:, tile] = s ∘ Σ_r V[idx[tile, r], :]ᵀ, accumulated over the
     (grid block, bin chunk) axes of the launch grid."""
@@ -84,9 +123,8 @@ def _z_matmul_kernel(idx_ref, v_ref, s_ref, y_ref, *, d_g, dc, block_r):
 
     def body(r, acc):
         base = (g * block_r + r) * d_g + c * dc
-        onehot = _onehot_t(idx_ref[pl.ds(r, 1), :], base, dc, v_ref.dtype)
-        return acc + jax.lax.dot(v_ref[r, 0], onehot, precision=_HIGHEST,
-                                 preferred_element_type=jnp.float32)
+        onehot = _onehot_t(idx_ref[pl.ds(r, 1), :], base, dc, jnp.bfloat16)
+        return acc + _gather_chunk(v_ref[r, 0], onehot)
 
     y_ref[...] += jax.lax.fori_loop(0, block_r, body,
                                     jnp.zeros(y_ref.shape, jnp.float32))

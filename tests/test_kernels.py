@@ -1,5 +1,7 @@
 """Per-kernel allclose sweeps: Pallas (interpret=True) and the XLA
 production fallback against the pure-jnp oracles in kernels/ref.py."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -58,6 +60,90 @@ def test_z_matmul_matches_ref(n, r, d_g, k, impl, dtype):
     tol = 1e-5 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want), rtol=tol, atol=tol * r)
+
+
+def _wide_values(key, shape):
+    """Values of both signs whose magnitudes span 1e-6..1e6."""
+    k1, k2, k3 = jax.random.split(key, 3)
+    mag = 10.0 ** jax.random.uniform(k1, shape, jnp.float32, -6.0, 6.0)
+    sign = jnp.where(jax.random.bernoulli(k2, 0.5, shape), 1.0, -1.0)
+    return mag * sign * jax.random.uniform(k3, shape, jnp.float32, 1.0, 2.0)
+
+
+def test_bf16_terms_sum_back_exactly():
+    """The kernel's three-term bf16 split: every term is a bf16 value and
+    (hi + mid) + lo rebuilds v bit for bit, but for −0, which comes back as
+    +0 (v + 0)."""
+    from repro.kernels import ell_spmm
+    v = jnp.concatenate([
+        _wide_values(jax.random.PRNGKey(0), (1 << 16,)),
+        jnp.arange(1 << 17, dtype=jnp.float32),
+        jnp.array([0.0, -0.0], jnp.float32)])
+    terms = [np.asarray(t) for t in jax.jit(ell_spmm.bf16_terms)(v)]
+    for t in terms:
+        assert t.dtype == np.float32
+        assert np.array_equal(t.astype(jnp.bfloat16).astype(np.float32)
+                              .view(np.uint32), t.view(np.uint32))
+    hi, mid, lo = terms
+    assert np.array_equal(((hi + mid) + lo).view(np.uint32),
+                          (np.asarray(v) + np.float32(0)).view(np.uint32))
+
+
+def _z_matmul_highest(idx, v, s, d_g):
+    """The projection as the one-hot kernel formed it at Precision.HIGHEST:
+    per grid and bin chunk an f32 one-hot contracted in f32, summed over a
+    block's grids, then over (grid block, bin chunk) in the kernel's order,
+    and scaled last."""
+    from repro.kernels import ell_spmm
+    n, r = idx.shape
+    dc, block_r = ell_spmm.dg_chunk(d_g), ell_spmm.pick_block_r(r)
+    y = jnp.zeros((n, v.shape[1]), jnp.float32)
+    for g in range(0, r, block_r):
+        for c in range(0, d_g, dc):
+            acc = jnp.zeros_like(y)
+            for j in range(g, g + block_r):
+                base = j * d_g + c
+                onehot = (idx[:, j:j + 1] - base == jnp.arange(dc)).astype(
+                    jnp.float32)
+                acc = acc + jnp.dot(onehot, v[base:base + dc],
+                                    precision=jax.lax.Precision.HIGHEST)
+            y = y + acc
+    return y * s[:, None]
+
+
+# K + 1 columns (the factor and the degree dual): 4 and 8 fill one 8-row
+# tile, 11 two; d_g 512 and 1024 are one and two bin chunks
+@pytest.mark.parametrize("k1", [4, 8, 11])
+@pytest.mark.parametrize("d_g", [512, 1024])
+def test_z_matmul_bit_identical_to_highest(k1, d_g):
+    """The bf16 split gather equals the f32 HIGHEST one-hot product bit for
+    bit on a factor spanning 1e-6..1e6 with an integer dual column."""
+    n, r = 256, 8
+    idx = (jax.random.randint(jax.random.PRNGKey(k1), (n, r), 0, d_g)
+           + jnp.arange(r, dtype=jnp.int32)[None, :] * d_g)
+    dual = jax.random.randint(jax.random.PRNGKey(1), (r * d_g, 1), 0,
+                              1 << 17).astype(jnp.float32)
+    v = jnp.concatenate(
+        [_wide_values(jax.random.PRNGKey(2), (r * d_g, k1 - 1)), dual], 1)
+    s = jax.random.uniform(jax.random.PRNGKey(3), (n,), jnp.float32) + 0.5
+    want = np.asarray(_z_matmul_highest(idx, v, s, d_g))
+    got = np.asarray(ops.z_matmul(idx, v, s, d_g=d_g, impl="pallas"))
+    assert np.array_equal(got.view(np.uint32), want.view(np.uint32))
+
+
+def test_z_matmul_gather_stays_one_pass_under_highest():
+    """Under the fit's default_matmul_precision("highest") the gather is
+    still one DEFAULT-precision pass of the stacked terms: 3 × 16 rows."""
+    from repro.kernels import ell_spmm
+    args = (jnp.zeros((8, 128), jnp.int32), jnp.zeros((8, 1, 16, 512)),
+            jnp.ones((1, 128)))
+    with jax.default_matmul_precision("highest"):
+        text = str(jax.make_jaxpr(functools.partial(
+            ell_spmm.z_matmul_pallas, d_g=512, interpret=True))(*args))
+    dots = [d.split("preferred_element_type")[0]
+            for d in text.split(" = dot_general[")[1:]]
+    assert len(dots) == 1 and ":f32[48,128] = dot_general[" in text
+    assert "precision=(Precision.DEFAULT, Precision.DEFAULT)" in dots[0]
 
 
 @pytest.mark.parametrize("n,r,d_g,k", [

@@ -69,10 +69,13 @@ def test_rb_binning_compiles(one_chip, chip_route, n, d):
 # "oos" is the serving projection with the degree fused into its gather
 # (``RBMap.oos_project``), at the serve cells' top bucket and hash widths
 # (poker d_g 512, mnist d_g 2048) and K = 10, so the kernel gathers K + 1
-# columns.
+# columns. The z gather stacks an f32 factor's three bf16 terms on the row
+# axis: "z_k48" takes them past one 128-row MXU tile (144 rows), and
+# "z_bf16" gathers a bf16 factor as it is.
 @pytest.mark.parametrize("product,d_g", [
     (p, w) for p in ("z", "zt", "gram") for w in (256, 4096)] + [
-    ("oos", 512), ("oos", 2048)], ids=lambda v: str(v))
+    ("oos", 512), ("oos", 2048), ("z_k48", 512), ("z_bf16", 2048)],
+    ids=lambda v: str(v))
 def test_ell_products_compile(one_chip, chip_route, product, d_g):
     n = POKER[0]
     d = R * d_g
@@ -80,10 +83,11 @@ def test_ell_products_compile(one_chip, chip_route, product, d_g):
     scale = ((n,), jnp.float32)
     if product == "oos":
         count = _compile_oos(one_chip, d_g, 10)
-    elif product == "z":
+    elif product in ("z", "z_k48", "z_bf16"):
+        k = 48 if product == "z_k48" else K
+        dtype = jnp.bfloat16 if product == "z_bf16" else jnp.float32
         count = _compile(one_chip, lambda i, v, s: ops.z_matmul(
-            i, v, s, d_g=d_g, impl="pallas"), idx, ((d, K), jnp.float32),
-            scale)
+            i, v, s, d_g=d_g, impl="pallas"), idx, ((d, k), dtype), scale)
     elif product == "zt":
         count = _compile(one_chip, lambda i, u, s: ops.zt_matmul(
             i, u, s, d, d_g=d_g, impl="pallas"), idx, ((n, K), jnp.float32),
