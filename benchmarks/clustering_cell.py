@@ -6,12 +6,54 @@ eigensolver iteration (q = Ẑᵀu psum; y = Ẑq) at production scale —
 N = 100M embedding rows, R = 256 grids, d_g = 4096 (D ≈ 1M), K = 16
 Ritz vectors — on both production meshes, fp32 vs bf16-compressed psum.
 
-Writes dryrun_results/sc-rb-clustering__eigeniter[...].json records that
-benchmarks.roofline merges into §Roofline (kind = "clustering").
+Writes dryrun_results/sc-rb-clustering__eigeniter[...].json records
+(kind = "clustering") that benchmarks/roofline.py reads.
 """
 import argparse
 import json
+import re
 import time
+
+COLLECTIVE_OPS = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
+                  "collective-permute")
+_DTYPE_BYTES = {
+    "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
+    "s64": 8, "u64": 8, "s32": 4, "u32": 4, "s16": 2, "u16": 2,
+    "s8": 1, "u8": 1, "pred": 1, "c64": 8, "c128": 16,
+}
+_SHAPE_RE = re.compile(r"([a-z0-9]+)\[([0-9,]*)\]")
+
+
+def _shape_bytes(text: str) -> int:
+    """Sum byte sizes of every shape literal in an HLO result type string."""
+    total = 0
+    for dt, dims in _SHAPE_RE.findall(text):
+        if dt not in _DTYPE_BYTES:
+            continue
+        n = 1
+        for d in dims.split(","):
+            if d:
+                n *= int(d)
+        total += n * _DTYPE_BYTES[dt]
+    return total
+
+
+def parse_collectives(hlo: str) -> dict:
+    """Per-op-kind byte totals from the (post-SPMD, per-device) HLO text."""
+    out = {k: {"count": 0, "bytes": 0} for k in COLLECTIVE_OPS}
+    for line in hlo.splitlines():
+        stripped = line.strip()
+        m = re.match(r"^(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(.+?)\s+([\w\-]+)\(", stripped)
+        if not m:
+            continue
+        result_type, opname = m.groups()
+        for kind in COLLECTIVE_OPS:
+            if opname == kind or opname.startswith(kind + "-"):
+                # result type covers output bytes (per device)
+                out[kind]["count"] += 1
+                out[kind]["bytes"] += _shape_bytes(result_type)
+                break
+    return out
 
 
 def run(multi_pod: bool, compress: bool, out_dir: str,
@@ -21,7 +63,6 @@ def run(multi_pod: bool, compress: bool, out_dir: str,
     # r-chunk loop, transiently materializing all gathers); per-chip ratios
     # are representative and every term scales linearly in N.
     from repro.core.distributed import lower_clustering_cell
-    from repro.launch.dryrun import parse_collectives
     from repro.launch.mesh import make_production_mesh
 
     mesh = make_production_mesh(multi_pod=multi_pod)
@@ -56,10 +97,6 @@ def run(multi_pod: bool, compress: bool, out_dir: str,
             "bytes_accessed": float(cost.get("bytes accessed", -1.0)),
         },
         "collectives": parse_collectives(compiled.as_text()),
-        # MODEL_FLOPS for one iteration: zt + z products, 2 flops/MAC
-        "params": 0,
-        "active_params": 0,
-        "tokens": n,
         "clustering": {"n": n, "r": n_grids, "d_g": d_g, "k": k},
         # CPU backend widens bf16 collectives to f32 in HLO; the true TPU
         # psum payload is D·K·itemsize:

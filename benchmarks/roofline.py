@@ -1,23 +1,21 @@
-"""Roofline derivation from the dry-run artifacts (EXPERIMENTS.md §Roofline).
+"""Roofline of the distributed SC_RB eigensolver iteration.
 
-For every (arch × shape × mesh) JSON produced by ``repro.launch.dryrun``:
+Reads the dry-run records that ``benchmarks/clustering_cell.py`` writes
+(``dryrun_results/sc-rb-clustering__*.json``, ``kind = "clustering"``): one
+Gram mat-vec ``q = Ẑᵀu`` (psum over the row axes) then ``y = Ẑq``, lowered
+and compiled for the production meshes. For each record:
 
-  compute    = HLO_FLOPs_per_chip / 197e12        [s]  (bf16 MXU peak, v5e)
-  memory     = HLO_bytes_per_chip / 819e9         [s]  (HBM bandwidth)
-  collective = coll_bytes_per_chip / 50e9         [s]  (ICI per-link)
+  compute    = flops_per_chip / 197e12        [s]  (bf16 MXU peak, v5e)
+  memory     = bytes_per_chip / 819e9         [s]  (HBM bandwidth)
+  collective = coll_bytes_per_chip / 50e9     [s]  (ICI per-link)
 
-``compiled.cost_analysis()`` on the SPMD-partitioned module reports the
-*per-device* program, so flops/bytes are already per-chip; collective bytes
-come from summing operand sizes of all-gather / all-reduce / reduce-scatter /
-all-to-all / collective-permute in the per-device HLO text.
-
-Caveats (recorded in EXPERIMENTS.md): the CPU backend widens bf16 buffers to
-f32, so the memory term is an upper bound (true TPU bytes ≥ ½ of reported);
-ring-topology factors ((n−1)/n) are folded into the single-link model.
-
-MODEL_FLOPS = 6·N·tokens (train), 2·N·tokens (prefill), 2·N·batch (decode),
-with N = active parameters for MoE. The ratio MODEL_FLOPS/HLO_FLOPs exposes
-remat/redundancy overhead (full-remat train ≈ 0.75 ideal).
+Two estimates are reported. The ``*_ub_s`` terms come from the compiled
+program: ``compiled.cost_analysis()`` on the SPMD-partitioned module is the
+per-device program, and collective bytes are the summed result sizes of the
+collectives in its HLO text. They are upper bounds, since the CPU backend
+widens bf16 buffers to f32. The analytic terms (``analytic_terms``) count
+the iteration from its shapes and decide the verdict: 4·N·R·K useful flops,
+the idx stream plus the gathered rows, and a D·K psum.
 """
 from __future__ import annotations
 
@@ -30,90 +28,27 @@ from typing import Dict, List
 PEAK_FLOPS = 197e12        # bf16 / chip (TPU v5e)
 HBM_BW = 819e9             # B/s / chip
 ICI_BW = 50e9              # B/s / link
-HBM_PER_CHIP = 16 * 2**30  # v5e
 
 
 def analytic_terms(rec: Dict) -> Dict[str, float]:
-    """First-principles per-chip traffic/flops model (the napkin math of
-    §Perf) — the best-estimate counterpart to the measured upper bounds,
-    assuming TPU-grade fusion (attention probs never round-trip HBM — the
-    Pallas flash-attention path; see kernels/flash_attention.py):
-
-      train:   weights bf16 ×3 passes ÷ TP  +  AdamW fp32 states RW
-               + activation carries (seq+batch sharded) ×6 RW
-               + attention KV/IO 12·T·D/L  +  head/embed streams
-      decode:  weight shards + KV-cache read (the fundamental bound)
-      collective (train): Megatron SP schedule — 4 activation gathers/layer
-               ×3 passes + FSDP weight AG ×3 + grad reduce-scatter
-    """
-    import repro.configs as C
-    if rec["kind"] == "clustering":
-        c = rec["clustering"]
-        chips = rec["n_devices"]
-        flops = 4 * c["n"] * c["r"] * c["k"] / chips
-        mem = (c["n"] * c["r"] * (4 + 4 * c["k"]) / chips     # idx + gather
-               + 2 * c["r"] * c["d_g"] * c["k"] * 4)          # q RW
-        coll = rec.get("coll_analytic_bytes",
-                       c["r"] * c["d_g"] * c["k"] * 4)
-        return {"flops": flops, "bytes": mem, "coll": coll}
-    cfg = C.get_config(rec["arch"])
-    shape = C.SHAPES[rec["shape"]]
+    """Per-chip flops, HBM bytes and collective bytes of one iteration."""
+    c = rec["clustering"]
     chips = rec["n_devices"]
-    pure_dp = cfg.dp_over_tp and shape.global_batch % chips == 0
-    tp = 1 if pure_dp else 16
-    dp = chips // tp
-    p, a = rec["params"], rec["active_params"]
-    l, d, v = cfg.n_layers, cfg.d_model, cfg.vocab_size
-    t = shape.seq_len * shape.global_batch
-    s, b = shape.seq_len, shape.global_batch
-    h, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    kv_bytes = 2 * l * b * s * hkv * hd * 2          # bf16 K+V cache, global
-    if cfg.mla is not None:
-        kv_bytes = l * b * s * (cfg.mla.kv_lora_rank + cfg.mla.qk_rope_dim) * 2
-    if cfg.ssm is not None and cfg.attn_chunk:       # ssm/hybrid state small
-        pass
-    attn_ctx = min(s, 10**9)
-    win_ctx = [min(seg.window or s, s) for seg in cfg.segments]
-    attn_flops = sum(
-        2 * 2 * b * s * min(w, s) * 0.5 * h * hd * seg.count
-        for w, seg in zip(win_ctx, cfg.segments)
-        if seg.mixer in ("gqa", "mla", "hybrid"))
-    if rec["kind"] == "train":
-        flops = (6 * a * t + 3 * attn_flops) * (4.0 / 3.0) / chips
-        mem = (3 * 2 * p / tp                          # bf16 weights, 3 passes
-               + 36 * p / chips                        # AdamW fp32 states RW
-               + 6 * l * t * d * 2 / chips             # carries RW (SP-sharded)
-               + 12 * l * t * d * 2 / chips            # attn/mlp IO
-               + 3 * 2 * d * v / tp + 8 * t * d / dp)  # head stream + hidden
-        coll = (3 * 2 * p / tp                         # FSDP weight AG
-                + 4 * p / dp                           # grad reduce-scatter
-                + 3 * 4 * l * (t / dp) * d * 2)        # SP gathers, 4/layer
-    elif rec["kind"] == "prefill":
-        flops = (2 * a * t + attn_flops) / chips
-        mem = (2 * p / tp + 6 * l * t * d * 2 / chips + kv_bytes / chips
-               + 2 * d * v / tp)
-        coll = 2 * p / tp + 4 * l * (t / dp) * d * 2
-    else:  # decode: one token over the cache
-        flops = 2 * a * b / chips + attn_flops / s / chips
-        mem = 2 * p / tp + kv_bytes / chips + 36.0 * b * d * l / chips
-        coll = 2 * b * d * l * 2 / dp + 2 * b * v * 4 / chips
+    flops = 4 * c["n"] * c["r"] * c["k"] / chips
+    mem = (c["n"] * c["r"] * (4 + 4 * c["k"]) / chips     # idx + gather
+           + 2 * c["r"] * c["d_g"] * c["k"] * 4)          # q RW
+    coll = rec.get("coll_analytic_bytes", c["r"] * c["d_g"] * c["k"] * 4)
     return {"flops": flops, "bytes": mem, "coll": coll}
 
 
-def load(results_dir: str, cost_dir: str = "cost_results") -> List[Dict]:
-    """Dry-run records, with trip-count-corrected costs merged in when the
-    cost-model probe (benchmarks.cost_model) has run for that cell."""
+def load(results_dir: str) -> List[Dict]:
+    """The clustering dry-run records under ``results_dir``."""
     rows = []
     for path in sorted(glob.glob(os.path.join(results_dir, "*.json"))):
         with open(path) as f:
             rec = json.load(f)
-        cpath = os.path.join(cost_dir, os.path.basename(path))
-        if os.path.exists(cpath):
-            with open(cpath) as f:
-                crec = json.load(f)
-            if crec.get("status") == "ok":
-                rec["corrected"] = crec["corrected"]
-        rows.append(rec)
+        if rec.get("kind") == "clustering":
+            rows.append(rec)
     return rows
 
 
@@ -121,20 +56,9 @@ def derive(rec: Dict) -> Dict:
     if rec.get("status") != "ok":
         return {**rec, "dominant": "n/a"}
     chips = rec["n_devices"]
-    corr = rec.get("corrected")
-    if corr is not None:
-        flops_chip = corr["flops"]
-        bytes_chip = corr["bytes"]
-        coll_chip = corr["coll_bytes"]
-        source = "cost_model"
-    else:  # raw cost_analysis (scan bodies counted once — lower bound)
-        flops_chip = rec["cost"]["flops"]
-        bytes_chip = rec["cost"]["bytes_accessed"]
-        coll_chip = sum(v["bytes"] for v in rec["collectives"].values())
-        source = "raw"
-    t_compute = flops_chip / PEAK_FLOPS
-    t_memory = bytes_chip / HBM_BW
-    t_coll = coll_chip / ICI_BW
+    flops_chip = rec["cost"]["flops"]
+    bytes_chip = rec["cost"]["bytes_accessed"]
+    coll_chip = sum(v["bytes"] for v in rec["collectives"].values())
     est = analytic_terms(rec)
     te_compute = est["flops"] / PEAK_FLOPS
     te_memory = est["bytes"] / HBM_BW
@@ -143,72 +67,50 @@ def derive(rec: Dict) -> Dict:
         [("compute", te_compute), ("memory", te_memory),
          ("collective", te_coll)],
         key=lambda kv: kv[1])[0]
-    n = rec["active_params"]
-    if rec["kind"] == "train":
-        model_flops = 6 * n * rec["tokens"]
-    elif rec["kind"] == "clustering":
-        c = rec["clustering"]   # one Gram iteration: Ẑᵀu + Ẑq, 2 flops/MAC
-        model_flops = 4 * c["n"] * c["r"] * c["k"]
-    else:
-        model_flops = 2 * n * rec["tokens"]
+    c = rec["clustering"]   # one Gram iteration: Ẑᵀu + Ẑq, 2 flops/MAC
+    model_flops = 4 * c["n"] * c["r"] * c["k"]
     hlo_flops_global = flops_chip * chips
     ratio = model_flops / hlo_flops_global if hlo_flops_global > 0 else 0.0
     bound_time = max(te_compute, te_memory, te_coll)
-    if rec["kind"] == "decode":
-        # decode is weight/cache streaming: ideal time = minimal bytes / BW
-        ideal_bytes = (2 * n / 16                              # bf16 shard/TP
-                       + rec["memory"]["argument_bytes"] * 0.5)
-        mfu_bound = (ideal_bytes / HBM_BW) / bound_time if bound_time else 0.0
-        mfu_bound = min(mfu_bound, 1.0)
-    elif rec["kind"] == "clustering":
-        # intrinsically streaming-bound (2 flops per 4 idx bytes): fraction =
-        # how close the binding term is to pure HBM streaming of Z
-        mfu_bound = te_memory / bound_time if bound_time else 0.0
-    else:
-        # fraction of roofline: useful model flops vs what the bound permits
-        mfu_bound = (model_flops / chips / PEAK_FLOPS) / bound_time \
-            if bound_time > 0 else 0.0
+    # intrinsically streaming-bound (2 flops per 4 idx bytes): fraction =
+    # how close the binding term is to pure HBM streaming of Z
+    fraction = te_memory / bound_time if bound_time else 0.0
     notes = {
-        "compute": "compute-bound: raise useful-FLOP fraction "
-                   "(less remat recompute, fuse elementwise chains)",
-        "memory": "memory-bound: increase arithmetic intensity "
-                  "(larger per-chip batch, bf16 end-to-end, fuse reads)",
-        "collective": "collective-bound: reshard to cut gathered bytes / "
-                      "overlap collectives with compute",
+        "compute": "compute-bound: raise the useful-flop fraction",
+        "memory": "memory-bound: stream fewer bytes of Z per mat-vec",
+        "collective": "collective-bound: compress or overlap the psum",
     }
     return {
         **rec,
-        "cost_source": source,
         # measured (HLO-derived, CPU-backend upper bounds)
-        "t_compute_ub_s": t_compute,
-        "t_memory_ub_s": t_memory,
-        "t_collective_ub_s": t_coll,
-        # analytic best-estimate (TPU-fusion model) — drives the verdicts
+        "t_compute_ub_s": flops_chip / PEAK_FLOPS,
+        "t_memory_ub_s": bytes_chip / HBM_BW,
+        "t_collective_ub_s": coll_chip / ICI_BW,
+        # analytic best estimate, which drives the verdicts
         "t_compute_s": te_compute,
         "t_memory_s": te_memory,
         "t_collective_s": te_coll,
         "dominant": dominant,
         "model_flops": model_flops,
         "useful_flop_ratio": ratio,
-        "roofline_fraction": mfu_bound,
+        "roofline_fraction": fraction,
         "note": notes[dominant],
     }
 
 
 def table(rows: List[Dict]) -> str:
-    hdr = ("| arch | shape | mesh | compute s | memory s | collective s | "
-           "dominant | MODEL/HLO flops | roofline frac | peak GiB/chip |\n"
-           "|---|---|---|---|---|---|---|---|---|---|\n")
+    hdr = ("| cell | mesh | compute s | memory s | collective s | "
+           "dominant | model/HLO flops | roofline frac | peak GiB/chip |\n"
+           "|---|---|---|---|---|---|---|---|---|\n")
     lines = []
     for r in rows:
-        if r.get("status") == "skipped":
-            lines.append(
-                f"| {r['arch']} | {r['shape']} | {r['mesh']} | — | — | — | "
-                f"skipped | — | — | — |")
+        if r.get("status") != "ok":
+            lines.append(f"| {r['shape']} | {r['mesh']} | — | — | — | "
+                         f"{r.get('status')} | — | — | — |")
             continue
         mem_gib = r["memory"]["peak_bytes_per_device"] / 2**30
         lines.append(
-            f"| {r['arch']} | {r['shape']} | {r['mesh']} "
+            f"| {r['shape']} | {r['mesh']} "
             f"| {r['t_compute_s']:.3e} | {r['t_memory_s']:.3e} "
             f"| {r['t_collective_s']:.3e} | **{r['dominant']}** "
             f"| {r['useful_flop_ratio']:.2f} | {r['roofline_fraction']:.2f} "
@@ -219,29 +121,13 @@ def table(rows: List[Dict]) -> str:
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--results-dir", default="dryrun_results")
-    ap.add_argument("--cost-dir", default="cost_results")
     ap.add_argument("--out", default="bench_results/roofline.json")
-    ap.add_argument("--write-experiments", action="store_true",
-                    help="inject the single-pod table into EXPERIMENTS.md")
     args = ap.parse_args()
-    rows = [derive(r) for r in load(args.results_dir, args.cost_dir)]
+    rows = [derive(r) for r in load(args.results_dir)]
     os.makedirs(os.path.dirname(args.out), exist_ok=True)
     with open(args.out, "w") as f:
         json.dump(rows, f, indent=1)
-    single = [r for r in rows if r.get("mesh") == "pod16x16"]
-    print(table(single))
-    if args.write_experiments:
-        marker = "<!-- ROOFLINE_TABLE -->"
-        with open("EXPERIMENTS.md") as f:
-            doc = f.read()
-        head, _, tail = doc.partition(marker)
-        # drop any previously injected table (up to the next blank heading)
-        rest = tail.split("\n\n(table inserted", 1)
-        keep = "\n\n(table inserted" + rest[1] if len(rest) > 1 else tail
-        block = marker + "\n\n" + table(single) + "\n"
-        with open("EXPERIMENTS.md", "w") as f:
-            f.write(head + block + keep)
-        print("\n[roofline] table written into EXPERIMENTS.md")
+    print(table(rows))
 
 
 if __name__ == "__main__":

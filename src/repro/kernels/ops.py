@@ -459,33 +459,3 @@ def kmeans_assign_stats(
         jnp.ones(x.shape[:1], jnp.float32), labels, num_segments=k)
     sums = jax.ops.segment_sum(x.astype(jnp.float32), labels, num_segments=k)
     return labels, counts, sums, jnp.sum(dists)
-
-
-# --------------------------------------------------------------------------
-# flash attention (forward) — serving/prefill deployment path
-# --------------------------------------------------------------------------
-
-def flash_attention(
-    q: jax.Array,          # (B, S, H, hd)
-    k: jax.Array,          # (B, T, H, hd)  (KV pre-repeated to H heads)
-    v: jax.Array,
-    *,
-    causal: bool = True,
-    window=None,
-    impl: str = "auto",
-) -> jax.Array:
-    """Online-softmax attention; scores never materialize in HBM."""
-    from repro.kernels import flash_attention as _fa, ref as _ref
-    b, s, h, hd = q.shape
-    t = k.shape[1]
-    fold = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], hd)
-    unfold = lambda x: x.reshape(b, h, s, hd).transpose(0, 2, 1, 3)
-    impl = _resolve(impl)
-    if impl == "xla":
-        return unfold(_ref.flash_attention_ref(
-            fold(q), fold(k), fold(v), causal=causal, window=window))
-    bq = _largest_divisor(s, 256)
-    bkv = _largest_divisor(t, 256)
-    return unfold(_fa.flash_attention_pallas(
-        fold(q), fold(k), fold(v), causal=causal, window=window,
-        block_q=bq, block_kv=bkv, interpret=not _on_tpu()))

@@ -79,29 +79,3 @@ def kmeans_assign_ref(
     labels = jnp.argmin(d2, axis=-1).astype(jnp.int32)
     best = jnp.min(d2, axis=-1)
     return labels, jnp.maximum(best, 0.0)
-
-
-def flash_attention_ref(
-    q: jax.Array,          # (BH, S, hd)
-    k: jax.Array,          # (BH, T, hd)
-    v: jax.Array,          # (BH, T, hd)
-    *,
-    causal: bool = True,
-    window=None,
-) -> jax.Array:
-    """Dense softmax attention oracle for the flash kernel."""
-    s_len, t_len = q.shape[1], k.shape[1]
-    scale = 1.0 / np.sqrt(q.shape[-1])
-    scores = jnp.einsum("bsd,btd->bst", q, k,
-                        preferred_element_type=jnp.float32) * scale
-    qpos = jnp.arange(s_len)[:, None]
-    kpos = jnp.arange(t_len)[None, :]
-    allow = jnp.ones((s_len, t_len), bool)
-    if causal:
-        allow &= kpos <= qpos
-    if window is not None:
-        allow &= kpos > qpos - window
-    scores = jnp.where(allow[None], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum("bst,btd->bsd", probs.astype(v.dtype), v,
-                      preferred_element_type=jnp.float32).astype(q.dtype)

@@ -1,16 +1,19 @@
-"""Production mesh definitions.
+"""Device meshes for the SC_RB plans.
 
-A function (never a module-level constant) so importing this module never
-touches jax device state — callers control when devices materialize.
+Functions (never module-level constants), so importing this module never
+touches jax device state: callers control when devices materialize.
 
 Axes:
+  - host:       (data=n)                     — every local device
   - single-pod: (data=16, model=16)          — 256 chips (one v5e pod)
   - multi-pod:  (pod=2, data=16, model=16)   — 512 chips (2 pods)
 
-``pod`` composes with ``data`` in every FSDP/batch PartitionSpec
-(``('pod','data')``), so scaling to N pods is a mesh-shape change only; the
-only inter-pod collective in training is the DP gradient reduction, matching
-the slow-link hierarchy.
+Rows (N) are sharded over ``data_axes(mesh)``: ``pod`` composed with
+``data``, in that nesting order, so scaling to N pods is a mesh-shape change
+only. The mesh plans (``repro.core.distributed``, ``MeshRows``) shard rows
+over those axes and psum the (D, K) Gram products across them; ``model``
+carries no rows, and the partitioned fit takes one device per row shard
+(``partition_devices``).
 """
 from __future__ import annotations
 

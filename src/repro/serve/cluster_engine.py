@@ -1,9 +1,7 @@
 """Continuous-batching predict serving engine over fitted ``SCRBModel``s.
 
-The LM engine next door (``serve/engine.py``) serves a fixed-shape decode
-step from fixed slots; predict serving inverts the problem — the *model*
-state is tiny (O(D·K)) and fixed, the *requests* are ragged. ``ClusterEngine``
-therefore batches on rows, not slots:
+In predict serving the *model* state is tiny (O(D·K)) and fixed while the
+*requests* are ragged, so ``ClusterEngine`` batches on rows:
 
 - **Bucketed jit cache** — requests for one (model, mode) are coalesced and
   padded up to a small geometric bucket grid (``model.BUCKET_GRID``), so each

@@ -1,28 +1,17 @@
-"""Shared small utilities: timers, rng plumbing, tree helpers, logging."""
+"""Shared small utilities: stage timers, rng plumbing, host-to-device
+prefetch, meshes and the compile cache."""
 from __future__ import annotations
 
 import contextlib
-import dataclasses
-import logging
 import os
 import time
 import warnings
 from typing import Any, Callable, Dict, Iterator
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 
 from repro.obs import metrics as _metrics
 from repro.obs import trace as _trace
-
-logger = logging.getLogger("repro")
-if not logger.handlers:
-    _h = logging.StreamHandler()
-    _h.setFormatter(logging.Formatter("[%(asctime)s] %(name)s %(levelname)s %(message)s", "%H:%M:%S"))
-    logger.addHandler(_h)
-    logger.setLevel(logging.INFO)
-
 
 _STAGE_SECONDS = _metrics.REGISTRY.histogram(
     "repro_stage_seconds", "Pipeline stage wall-clock seconds.", ("stage",))
@@ -69,18 +58,6 @@ class StageTimer:
         return f"StageTimer({inner}, total={self.total:.3f}s)"
 
 
-def tree_bytes(tree: Any) -> int:
-    """Total byte footprint of a pytree of arrays / ShapeDtypeStructs."""
-    leaves = jax.tree_util.tree_leaves(tree)
-    return sum(int(np.prod(l.shape)) * jnp.dtype(l.dtype).itemsize for l in leaves)
-
-
-def tree_params(tree: Any) -> int:
-    """Total element count of a pytree of arrays / ShapeDtypeStructs."""
-    leaves = jax.tree_util.tree_leaves(tree)
-    return sum(int(np.prod(l.shape)) for l in leaves)
-
-
 def fold_key(key: jax.Array, *names: str) -> jax.Array:
     """Deterministically derive a subkey from string tags (stable across hosts)."""
     for name in names:
@@ -89,12 +66,6 @@ def fold_key(key: jax.Array, *names: str) -> jax.Array:
             h = ((h ^ ch) * 16777619) & 0xFFFFFFFF
         key = jax.random.fold_in(key, int(h))
     return key
-
-
-def asdict_shallow(obj: Any) -> Dict[str, Any]:
-    if dataclasses.is_dataclass(obj):
-        return {f.name: getattr(obj, f.name) for f in dataclasses.fields(obj)}
-    raise TypeError(f"not a dataclass: {obj!r}")
 
 
 _PREFETCH_ITEMS = _metrics.REGISTRY.counter(
@@ -147,8 +118,8 @@ def prefetch_to_device(
             measure = stats
 
     def put(t):
-        # not tree_bytes(): prefetched items may carry scalar leaves
-        # (chunk indices) alongside the arrays
+        # prefetched items may carry scalar leaves (chunk indices)
+        # alongside the arrays
         nbytes = sum(int(getattr(leaf, "nbytes", 0))
                      for leaf in jax.tree_util.tree_leaves(t))
         if measure is not None:

@@ -1,8 +1,9 @@
 """Test-tier configuration: fast by default, opt into the slow tier.
 
-Tier-1 (`PYTHONPATH=src python -m pytest -x -q`) must stay green and finish
-in well under a minute on CPU, so long-running pipeline/theory/distributed
-cases are marked ``slow`` and deselected unless ``--runslow`` is given.
+Tier-1 (`PYTHONPATH=src python -m pytest -x -q`) must stay green on CPU.
+Cases that take minutes there (long pipeline/theory/distributed runs) are
+marked ``slow`` and deselected unless ``--runslow`` is given; a case of a
+few seconds stays in tier-1.
 """
 import pytest
 

@@ -95,7 +95,6 @@ def small_reference():
     return x, sc_rb(jnp.asarray(x), SCRBConfig(**SMALL, impl="xla"))
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("residency,prefetch", _GRID)
 def test_plan_grid_pallas_interpret(small_reference, residency, prefetch):
     """The pallas rows of the plan grid: kernel dispatch is orthogonal to

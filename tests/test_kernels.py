@@ -25,8 +25,8 @@ def _rb_inputs(key, n, d, r, d_g):
 @pytest.mark.parametrize("n,d,r,d_g", [
     (64, 2, 8, 64),
     (100, 3, 16, 128),     # n not divisible by tile
-    pytest.param(256, 7, 4, 256, marks=pytest.mark.slow),
-    pytest.param(513, 16, 32, 512, marks=pytest.mark.slow),  # odd n, wide d
+    (256, 7, 4, 256),
+    (513, 16, 32, 512),    # odd n, wide d
     (40, 150, 8, 64),      # d over one 128-wide block: hashes accumulate
 ])
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
@@ -41,9 +41,9 @@ def test_rb_binning_matches_ref(n, d, r, d_g, impl):
 @pytest.mark.parametrize("n,r,d_g,k", [
     (64, 4, 64, 8),
     (100, 8, 128, 3),      # ragged n
-    pytest.param(256, 16, 64, 32, marks=pytest.mark.slow),
+    (256, 16, 64, 32),
     # no multiple of 8 divides r -> one block of all R grids
-    pytest.param(300, 12, 256, 5, marks=pytest.mark.slow),
+    (300, 12, 256, 5),
 ])
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -112,9 +112,10 @@ def _z_matmul_highest(idx, v, s, d_g):
 
 
 # K + 1 columns (the factor and the degree dual): 4 and 8 fill one 8-row
-# tile, 11 two; d_g 512 and 1024 are one and two bin chunks
+# tile, 11 two; d_g 512, 1024, 2048 and 4096 are 1, 2, 4 and 8 bin chunks,
+# the last two the widths the zoo serves (mnist, covtype-mult; acoustic)
 @pytest.mark.parametrize("k1", [4, 8, 11])
-@pytest.mark.parametrize("d_g", [512, 1024])
+@pytest.mark.parametrize("d_g", [512, 1024, 2048, 4096])
 def test_z_matmul_bit_identical_to_highest(k1, d_g):
     """The bf16 split gather equals the f32 HIGHEST one-hot product bit for
     bit on a factor spanning 1e-6..1e6 with an integer dual column."""
@@ -149,7 +150,7 @@ def test_z_matmul_gather_stays_one_pass_under_highest():
 @pytest.mark.parametrize("n,r,d_g,k", [
     (64, 4, 64, 8),
     (100, 8, 128, 3),
-    pytest.param(256, 16, 64, 32, marks=pytest.mark.slow),
+    (256, 16, 64, 32),
 ])
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
 def test_zt_matmul_matches_ref(n, r, d_g, k, impl):
@@ -188,8 +189,7 @@ def test_bin_counts_matches_exact(n, r, d_g, impl):
 @pytest.mark.parametrize("n,r,d_g,k,chunk", [
     (101, 8, 2, 3, 32),    # non-divisible N, minimal d_g, ragged chunks
     (100, 4, 128, 5, 64),  # ragged last chunk
-    pytest.param(256, 8, 512, 4, 256,  # single chunk == whole matrix
-                 marks=pytest.mark.slow),
+    (256, 8, 512, 4, 256),  # single chunk == whole matrix
     (130, 4, 64, 2, 7),    # many tiny ragged chunks
 ])
 @pytest.mark.parametrize("impl", ["xla", "pallas", "auto"])
@@ -256,8 +256,8 @@ def test_zt_z_adjoint():
 
 @pytest.mark.parametrize("n,d,k", [
     (64, 2, 3),
-    pytest.param(1000, 8, 16, marks=pytest.mark.slow),
-    pytest.param(1025, 16, 7, marks=pytest.mark.slow),
+    (1000, 8, 16),
+    (1025, 16, 7),
 ])
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
 def test_kmeans_assign_matches_ref(n, d, k, impl):
@@ -267,47 +267,6 @@ def test_kmeans_assign_matches_ref(n, d, k, impl):
     got_l, got_d = ops.kmeans_assign(x, c, impl=impl)
     assert np.array_equal(np.asarray(got_l), np.asarray(want_l))
     np.testing.assert_allclose(np.asarray(got_d), np.asarray(want_d), rtol=1e-4, atol=1e-4)
-
-
-@pytest.mark.parametrize("s,t,hd,causal,window", [
-    (64, 64, 16, True, None),
-    pytest.param(128, 128, 32, True, None, marks=pytest.mark.slow),
-    (64, 64, 16, True, 24),       # sliding window
-    pytest.param(128, 128, 16, False, None,  # bidirectional
-                 marks=pytest.mark.slow),
-])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_attention_matches_ref(s, t, hd, causal, window, dtype):
-    key = jax.random.PRNGKey(s + hd)
-    b, h = 2, 3
-    q = jax.random.normal(key, (b, s, h, hd), jnp.float32).astype(dtype)
-    k = jax.random.normal(jax.random.fold_in(key, 1), (b, t, h, hd),
-                          jnp.float32).astype(dtype)
-    v = jax.random.normal(jax.random.fold_in(key, 2), (b, t, h, hd),
-                          jnp.float32).astype(dtype)
-    want = ops.flash_attention(q, k, v, causal=causal, window=window,
-                               impl="xla")
-    got = ops.flash_attention(q, k, v, causal=causal, window=window,
-                              impl="pallas")
-    tol = 2e-5 if dtype == jnp.float32 else 3e-2
-    np.testing.assert_allclose(
-        np.asarray(got, np.float32), np.asarray(want, np.float32),
-        rtol=tol, atol=tol)
-
-
-def test_flash_attention_blocked_tiling():
-    """Non-trivial multi-block grid (block 64 over 256 seq)."""
-    from repro.kernels.flash_attention import flash_attention_pallas
-    from repro.kernels.ref import flash_attention_ref
-    key = jax.random.PRNGKey(0)
-    q = jax.random.normal(key, (2, 256, 32), jnp.float32)
-    k = jax.random.normal(jax.random.fold_in(key, 1), (2, 256, 32))
-    v = jax.random.normal(jax.random.fold_in(key, 2), (2, 256, 32))
-    got = flash_attention_pallas(q, k, v, causal=True, block_q=64,
-                                 block_kv=64, interpret=True)
-    want = flash_attention_ref(q, k, v, causal=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
 
 
 # --------------------------------------------------------------------------
@@ -376,7 +335,7 @@ def _gram_inputs(n, r, d_g, k, seed=0):
 @pytest.mark.parametrize("n,r,d_g,k", [
     (64, 4, 64, 8),
     (100, 8, 128, 3),      # ragged n -> padded tiles
-    pytest.param(300, 12, 64, 5, marks=pytest.mark.slow),  # r % 4 != 0
+    (300, 12, 64, 5),      # r % 4 != 0
 ])
 @pytest.mark.parametrize("impl", ["xla", "pallas", "auto"])
 def test_gram_matmul_matches_ref(n, r, d_g, k, impl):

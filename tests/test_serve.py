@@ -1,6 +1,5 @@
-"""Serving engine tests: the LM generation loop (sampling, EOS, cache
-reuse) and the cluster predict engine (bucketed jit cache, coalescing,
-LRU, hot-swap, HTTP front end)."""
+"""Serving engine tests: the cluster predict engine (bucketed jit cache,
+coalescing, LRU, hot-swap, HTTP front end)."""
 import glob
 import importlib.util
 import json
@@ -14,74 +13,8 @@ import pytest
 
 from repro.core import SCRBConfig, SCRBModel, rb
 from repro.data.synthetic import make_blobs, make_rings
-from repro.models import transformer as T
-from repro.models.config import ModelConfig, dense_segments
 from repro.serve.cluster_engine import ClusterEngine, EngineConfig
-from repro.serve.engine import Engine, ServeConfig, sample
 from repro.serve.server import ClusterServer
-
-
-def _tiny():
-    return ModelConfig(
-        name="t", family="dense", d_model=32, n_heads=2, n_kv_heads=2,
-        head_dim=16, d_ff=64, vocab_size=128, segments=dense_segments(2),
-        dtype="float32", remat="none", attn_chunk=32, loss_chunk=128)
-
-
-def test_greedy_sampling_is_argmax():
-    logits = jnp.array([[0.1, 5.0, -1.0], [2.0, 0.0, 3.0]])
-    out = sample(logits, jax.random.PRNGKey(0), 0.0)
-    assert out.tolist() == [1, 2]
-
-
-@pytest.mark.slow
-def test_generate_shapes_and_determinism():
-    cfg = _tiny()
-    params = T.init_params(cfg, jax.random.PRNGKey(0))
-    eng = Engine(cfg, params, ServeConfig(cache_len=48, batch_size=2,
-                                          temperature=0.0))
-    prompts = np.random.default_rng(0).integers(
-        0, cfg.vocab_size, size=(2, 8)).astype(np.int32)
-    out1 = eng.generate(prompts, 16, seed=3)
-    out2 = eng.generate(prompts, 16, seed=3)
-    assert out1.shape == (2, 16)
-    np.testing.assert_array_equal(out1, out2)
-    assert out1.max() < cfg.vocab_size
-
-
-@pytest.mark.slow
-def test_generate_matches_stepwise_teacher_forcing():
-    """Greedy engine output == manual prefill+decode loop."""
-    cfg = _tiny()
-    params = T.init_params(cfg, jax.random.PRNGKey(1))
-    prompts = np.random.default_rng(1).integers(
-        0, cfg.vocab_size, size=(1, 8)).astype(np.int32)
-    eng = Engine(cfg, params, ServeConfig(cache_len=16, batch_size=1,
-                                          temperature=0.0))
-    out = eng.generate(prompts, 4, seed=0)
-
-    caches = T.init_cache(cfg, 1, 16)
-    logits, caches = T.prefill(cfg, params, {"tokens": jnp.asarray(prompts)},
-                               caches)
-    toks = []
-    tok = jnp.argmax(logits, -1).astype(jnp.int32)
-    for i in range(4):
-        toks.append(int(tok[0]))
-        logits, caches = T.decode_step(cfg, params, tok, caches,
-                                       jnp.int32(8 + i))
-        tok = jnp.argmax(logits, -1).astype(jnp.int32)
-    np.testing.assert_array_equal(out[0], np.array(toks))
-
-
-def test_eos_stops_generation():
-    cfg = _tiny()
-    params = T.init_params(cfg, jax.random.PRNGKey(2))
-    eng = Engine(cfg, params, ServeConfig(cache_len=64, batch_size=1,
-                                          temperature=0.0, eos_token=999))
-    # vocab < 999 so EOS never fires; just exercises the code path
-    prompts = np.zeros((1, 4), np.int32)
-    out = eng.generate(prompts, 8, seed=0)
-    assert out.shape == (1, 8)
 
 
 # -- ClusterEngine ---------------------------------------------------------
