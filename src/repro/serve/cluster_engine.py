@@ -24,12 +24,18 @@ In predict serving the *model* state is tiny (O(D·K)) and fixed while the
   buffers but *keeps compiled cells* — they close over shapes only, state is
   passed as arguments, so a re-faulted model pays one H2D, zero recompiles.
 
-The engine is synchronous and single-threaded by design: ``submit`` enqueues
-and returns a ticket, ``step`` runs one coalesced device batch, ``drain``
-runs until idle, ``take`` collects a finished ticket. ``serve/server.py``
-puts a stdlib-HTTP front end (with a lock) over the same loop, and
-``predict``/``transform`` are one-call sync wrappers — benchmarks, tests,
-and the server all exercise the identical path.
+- **One batch in flight** — JAX dispatch is asynchronous, so ``step``
+  stages, copies and dispatches the next batch while the device still runs
+  the one before it (when that one is not yet ready and rows remain), then
+  reads back the oldest batch. At most two batches are ever on the device;
+  each ``step`` completes exactly one, in dispatch order.
+
+The engine is single-threaded by design: ``submit`` enqueues and returns a
+ticket, ``step`` completes one coalesced device batch, ``drain`` runs until
+nothing is pending or in flight, ``take`` collects a finished ticket.
+``serve/server.py`` puts a stdlib-HTTP front end (with a lock) over the same
+loop, and ``predict``/``transform`` are one-call sync wrappers — benchmarks,
+tests, and the server all exercise the identical path.
 """
 from __future__ import annotations
 
@@ -63,7 +69,8 @@ class EngineConfig:
     buckets: Tuple[int, ...] = _model.BUCKET_GRID
     max_resident_models: int = 4          # LRU capacity (count)
     device_budget_bytes: Optional[int] = None   # LRU capacity (bytes)
-    ring_slots: int = 2                   # staging buffers per bucket shape
+    ring_slots: int = 2                   # staging buffers per bucket
+    # shape; ≥ 2, so a batch is staged while the previous one is in flight
     max_batch_rows: Optional[int] = None  # coalescing cap per device launch;
     # None → top bucket
     impl: Optional[str] = None            # kmeans_assign impl override
@@ -77,6 +84,9 @@ class EngineConfig:
         if tuple(sorted(self.buckets)) != tuple(self.buckets) or \
                 len(self.buckets) == 0 or self.buckets[0] < 1:
             raise ValueError(f"buckets must be ascending and ≥1: {self.buckets}")
+        if self.ring_slots < 2:
+            raise ValueError("ring_slots must be ≥ 2: the next batch is "
+                             "staged while the previous one is in flight")
 
 
 class _StagingRing:
@@ -84,11 +94,13 @@ class _StagingRing:
 
     ``get`` hands out the least-recently-used buffer once ``slots`` exist for
     a shape; before that it allocates (counted — the bench gates that the
-    steady-state delta is zero).
+    steady-state delta is zero). With two or more slots the buffer handed out
+    is never the one handed out just before it, which may still feed the
+    batch in flight.
     """
 
     def __init__(self, slots: int):
-        self.slots = max(1, int(slots))
+        self.slots = int(slots)
         self._rings: Dict[Tuple[int, int], collections.deque] = {}
         self.allocations = 0
 
@@ -124,8 +136,26 @@ class _Request:
     x: np.ndarray
     out: np.ndarray
     submitted_at: float
-    cursor: int = 0               # rows already served (oversize requests
-    completed_at: Optional[float] = None   # span several batches)
+    cursor: int = 0           # rows answered; oversize requests span batches
+    dispatched: int = 0       # rows sent to the device, never below cursor
+    completed_at: Optional[float] = None
+
+
+@dataclasses.dataclass
+class _Batch:
+    """A dispatched device batch, waiting to be read back."""
+
+    model: str
+    mode: str
+    take: List[Tuple[_Request, int, int]]   # (request, first row, rows)
+    rows: int
+    bucket: int
+    buf: np.ndarray           # staging buffer; the ring's until read back
+    out: Any                  # the cell's output, still on the device
+    requests: int             # requests whose first rows it takes
+    queue_wait: float         # their summed wait, submit → dispatch (s)
+    switched: bool            # model differs from the batch before it
+    overlapped: bool          # dispatched while another was unread
 
 
 @dataclasses.dataclass
@@ -154,7 +184,8 @@ class ClusterEngine:
     per-(model, mode) request-latency histogram. ``stats()`` reconstructs
     the historical dict shape from the registry — same keys, same ints —
     plus ``latency_*`` and ``queue_wait_*`` p50/p99 in ms (the queue wait
-    runs from ``submit`` to the step that takes a request's first rows);
+    runs from ``submit`` to the dispatch of the batch that takes a request's
+    first rows);
     ``metrics_text()`` renders
     the registry (plus the process-global one) in Prometheus format for
     ``GET /metrics``.
@@ -168,10 +199,12 @@ class ClusterEngine:
             collections.OrderedDict()
         self._cells: Dict[Tuple[str, int, str], Any] = {}
         self._ring = _StagingRing(self.config.ring_slots)
+        # unfinished requests, in flight ones included, oldest first
         self._pending: "collections.deque[_Request]" = collections.deque()
+        self._inflight: "collections.deque[_Batch]" = collections.deque()
         self._results: Dict[int, _Request] = {}
         self._tickets = itertools.count()
-        self._last_model: Optional[str] = None   # model of the latest step
+        self._last_model: Optional[str] = None   # latest dispatched batch's
         self.registry = obs_metrics.MetricsRegistry()
         self._counters: Dict[str, obs_metrics.Counter] = {
             key: self.registry.counter(
@@ -188,6 +221,10 @@ class ClusterEngine:
             "engine_fused_degree_batches_total",
             "Batches whose cell read the degree from the projection's "
             "gather.", ("model", "mode"))
+        self._overlapped_batches = self.registry.counter(
+            "engine_overlapped_batches_total",
+            "Batches dispatched while an earlier batch was still unread.",
+            ("model", "mode"))
         self._queue_wait_hist = self.registry.histogram(
             "engine_queue_wait_seconds",
             "Per-request submit→first batch wait.", ("model", "mode"))
@@ -221,6 +258,7 @@ class ClusterEngine:
             self._counters[key].inc(0, model=name)   # shows in /metrics now
         for mode in MODES:
             self._fused_batches.inc(0, model=name, mode=mode)
+            self._overlapped_batches.inc(0, model=name, mode=mode)
         return mdl
 
     def _ensure_resident(self, name: str) -> _Resident:
@@ -337,93 +375,135 @@ class ClusterEngine:
         return req.ticket
 
     def step(self) -> int:
-        """Serve one coalesced device batch for the oldest pending
-        (model, mode) group; returns rows served (0 when idle).
+        """Complete the oldest device batch; returns its rows (0 when idle).
 
-        Under a profiler session the step is one ``engine.batch`` span
-        (``requests``: the requests whose first rows it takes;
-        ``queue_wait_us``: the sum of their waits from ``submit`` to the
-        step's start; ``switched``: 1 when its model differs from the
-        previous step's, which ``engine_model_switches_total`` counts under
-        the model switched to) holding ``engine.stage`` (copy into the
-        staging buffer), ``engine.h2d`` (``device_put``),
-        ``engine.dispatch`` (the cell call) and ``engine.readback`` (the
-        blocking read of the output). Each request's wait also feeds
+        In order: with no batch in flight, dispatch one for the oldest
+        request that has rows not yet dispatched (coalescing its (model,
+        mode) group); if such rows remain and the batch in flight is not
+        ready, dispatch the next batch now, so its staging, copy and
+        dispatch overlap the device's work; then block on the oldest batch's
+        read-back and complete its requests. Counters and histograms other
+        than the queue wait move when a batch completes.
+
+        Under a profiler session each call is one ``engine.batch`` span,
+        with the completed batch's ``model``, ``mode``, ``bucket``,
+        ``rows``, ``requests`` (the requests whose first rows it takes),
+        ``queue_wait_us`` (the sum of their waits from ``submit`` to the
+        batch's dispatch) and ``switched`` (1 when its model differs from
+        the batch dispatched before it, which
+        ``engine_model_switches_total`` counts under the model switched
+        to). It holds ``engine.stage`` (copy into the staging buffer),
+        ``engine.h2d`` (``device_put``) and ``engine.dispatch`` (the cell
+        call; ``overlapped``: 1 when another batch was in flight, which
+        ``engine_overlapped_batches_total`` counts) for each batch it
+        dispatches, and ``engine.readback`` (the blocking read of the
+        output) for the batch it completes. Each request's wait also feeds
         ``engine_queue_wait_seconds``.
         """
         if not self._pending:
             return 0
-        started_at = time.perf_counter()
-        head = self._pending[0]
+        with obs_trace.span("engine.batch") as span:
+            if not self._inflight:
+                self._dispatch(self._next_head())
+            head = self._next_head()
+            if head is not None and not self._inflight[-1].out.is_ready():
+                self._dispatch(head)
+            batch = self._inflight.popleft()
+            self._complete(batch)
+            span.set_metadata(model=batch.model, mode=batch.mode,
+                              bucket=batch.bucket, rows=batch.rows,
+                              requests=batch.requests,
+                              queue_wait_us=1e6 * batch.queue_wait,
+                              switched=int(batch.switched))
+        return batch.rows
+
+    def _next_head(self) -> Optional[_Request]:
+        """The oldest request with rows not yet dispatched, if any."""
+        return next((req for req in self._pending
+                     if req.dispatched < req.x.shape[0]), None)
+
+    def _dispatch(self, head: _Request) -> None:
+        """Stage, copy and dispatch one coalesced batch of ``head``'s (model,
+        mode) group, up to the coalescing cap; it joins ``_inflight``."""
+        formed_at = time.perf_counter()
         name, mode = head.model, head.mode
-        with obs_trace.span("engine.batch", model=name, mode=mode) as batch:
-            cap = self.config.max_batch_rows or self.config.buckets[-1]
-            take: List[Tuple[_Request, int]] = []
-            total = 0
-            for req in self._pending:
-                if req.model != name or req.mode != mode:
-                    continue
-                if total >= cap:
-                    break
-                n = min(req.x.shape[0] - req.cursor, cap - total)
-                take.append((req, n))
-                total += n
-            bucket = _model.round_to_bucket(total, self.config.buckets)
-            dim = take[0][0].x.shape[1]
-            waits = [started_at - req.submitted_at for req, _ in take
-                     if req.cursor == 0]
-            for wait in waits:
-                self._queue_wait_hist.observe(wait, model=name, mode=mode)
-            switched = self._last_model not in (None, name)
-            self._last_model = name
-            batch.set_metadata(bucket=bucket, rows=total,
-                               requests=len(waits),
-                               queue_wait_us=1e6 * sum(waits),
-                               switched=int(switched))
-            res = self._ensure_resident(name)
-            cell = self._cell(name, bucket, mode, res, dim)
-            with obs_trace.span("engine.stage"):
-                buf = self._ring.get(bucket, dim)
-                off = 0
-                for req, n in take:
-                    buf[off:off + n] = req.x[req.cursor:req.cursor + n]
-                    off += n
-                buf[off:] = 0.0     # mask: pad rows are zeros, sliced off
-            with obs_trace.span("engine.h2d"):
-                xdev = jax.device_put(buf)
-            with obs_trace.span("engine.dispatch"):
-                if mode == "predict":
-                    out = cell(res.fm, res.dual, res.proj, res.cents, xdev)
-                else:
-                    out = cell(res.fm, res.dual, res.proj, xdev)
-            with obs_trace.span("engine.readback"):
-                out = np.asarray(out)       # blocks on the device result
-            done_at = time.perf_counter()
+        cap = self.config.max_batch_rows or self.config.buckets[-1]
+        take: List[Tuple[_Request, int, int]] = []
+        total = 0
+        for req in self._pending:
+            left = req.x.shape[0] - req.dispatched
+            if req.model != name or req.mode != mode or not left:
+                continue
+            if total >= cap:
+                break
+            n = min(left, cap - total)
+            take.append((req, req.dispatched, n))
+            total += n
+        bucket = _model.round_to_bucket(total, self.config.buckets)
+        dim = head.x.shape[1]
+        waits = [formed_at - req.submitted_at for req, first, _ in take
+                 if first == 0]
+        for wait in waits:
+            self._queue_wait_hist.observe(wait, model=name, mode=mode)
+        switched = self._last_model not in (None, name)
+        self._last_model = name
+        overlapped = bool(self._inflight)
+        res = self._ensure_resident(name)
+        cell = self._cell(name, bucket, mode, res, dim)
+        with obs_trace.span("engine.stage"):
+            buf = self._ring.get(bucket, dim)
             off = 0
-            for req, n in take:
-                req.out[req.cursor:req.cursor + n] = out[off:off + n]
-                req.cursor += n
+            for req, first, n in take:
+                buf[off:off + n] = req.x[first:first + n]
                 off += n
-                if req.cursor == req.x.shape[0]:
-                    req.completed_at = done_at
-                    self._results[req.ticket] = req
-                    self._pending.remove(req)
-                    self._requests_total.inc(model=name, mode=mode)
-                    self._latency_hist.observe(done_at - req.submitted_at,
-                                               model=name, mode=mode)
-            self._bump(name, "rows_served", total)
-            self._bump(name, "batches")
-            self._bump(name, "model_switches", int(switched))
-            mdl = self._models[name]
-            if featuremap.fused_degree(mdl.feature_map,
-                                       laplacian=mdl.laplacian_normalize):
-                self._fused_batches.inc(model=name, mode=mode)
-            self._bump(name, "padded_rows", bucket - total)
-            self._batch_rows_hist.observe(total, model=name)
-        return total
+            buf[off:] = 0.0     # mask: pad rows are zeros, sliced off
+        with obs_trace.span("engine.h2d"):
+            xdev = jax.device_put(buf)
+        with obs_trace.span("engine.dispatch", overlapped=int(overlapped)):
+            if mode == "predict":
+                out = cell(res.fm, res.dual, res.proj, res.cents, xdev)
+            else:
+                out = cell(res.fm, res.dual, res.proj, xdev)
+        for req, first, n in take:
+            req.dispatched = first + n
+        self._inflight.append(_Batch(
+            model=name, mode=mode, take=take, rows=total, bucket=bucket,
+            buf=buf, out=out, requests=len(waits), queue_wait=sum(waits),
+            switched=switched, overlapped=overlapped))
+
+    def _complete(self, batch: _Batch) -> None:
+        """Read ``batch`` back, answer its rows and bump the counters."""
+        name, mode = batch.model, batch.mode
+        with obs_trace.span("engine.readback"):
+            out = np.asarray(batch.out)     # blocks on the device result
+        done_at = time.perf_counter()
+        off = 0
+        for req, first, n in batch.take:
+            req.out[first:first + n] = out[off:off + n]
+            req.cursor += n
+            off += n
+            if req.cursor == req.x.shape[0]:
+                req.completed_at = done_at
+                self._results[req.ticket] = req
+                self._pending.remove(req)
+                self._requests_total.inc(model=name, mode=mode)
+                self._latency_hist.observe(done_at - req.submitted_at,
+                                           model=name, mode=mode)
+        self._bump(name, "rows_served", batch.rows)
+        self._bump(name, "batches")
+        self._bump(name, "model_switches", int(batch.switched))
+        mdl = self._models[name]
+        if featuremap.fused_degree(mdl.feature_map,
+                                   laplacian=mdl.laplacian_normalize):
+            self._fused_batches.inc(model=name, mode=mode)
+        self._overlapped_batches.inc(int(batch.overlapped), model=name,
+                                     mode=mode)
+        self._bump(name, "padded_rows", batch.bucket - batch.rows)
+        self._batch_rows_hist.observe(batch.rows, model=name)
 
     def drain(self) -> int:
-        """Run ``step`` until the queue is empty; returns rows served."""
+        """Run ``step`` until no request is pending (in flight ones stay
+        pending until read back); returns rows served."""
         total = 0
         while self._pending:
             total += self.step()
@@ -461,16 +541,20 @@ class ClusterEngine:
 
     def _model_stat_dict(self, name: str) -> Dict[str, int]:
         """One model's ``STAT_KEYS`` dict, read from the registry counters
-        (``model_switches``: its steps that followed another model's),
-        plus ``fused_degree_batches`` over both modes: its ratio to
-        ``batches`` is the share of batches whose degree came from the
-        projection's gather."""
+        (``model_switches``: its batches that followed another model's),
+        plus ``fused_degree_batches`` and ``overlapped_batches`` over both
+        modes: their ratios to ``batches`` are the shares of batches whose
+        degree came from the projection's gather, and that were dispatched
+        while another batch was in flight."""
         if name not in self._models:
             raise KeyError(name)
         d = {key: int(self._counters[key].get(model=name))
              for key in STAT_KEYS}
-        d["fused_degree_batches"] = int(sum(
-            self._fused_batches.get(model=name, mode=mode) for mode in MODES))
+        for key, counter in (("fused_degree_batches", self._fused_batches),
+                             ("overlapped_batches",
+                              self._overlapped_batches)):
+            d[key] = int(sum(counter.get(model=name, mode=mode)
+                             for mode in MODES))
         return d
 
     def latency_quantiles(self, name: str, mode: str = "predict",
@@ -509,6 +593,8 @@ class ClusterEngine:
             "batches": sum(s["batches"] for s in per.values()),
             "fused_degree_batches": sum(s["fused_degree_batches"]
                                         for s in per.values()),
+            "overlapped_batches": sum(s["overlapped_batches"]
+                                      for s in per.values()),
             "padded_rows": sum(s["padded_rows"] for s in per.values()),
             "evictions": sum(s["evictions"] for s in per.values()),
             "model_switches": sum(s["model_switches"] for s in per.values()),

@@ -452,12 +452,20 @@ def test_engine_batch_spans_phases_and_queue_wait(model, tmp_path):
     evs = _events(tmp_path, {"engine.batch", *phases})
     batches = [e for e in evs if e.name == "engine.batch"]
     assert len(batches) == 2
+    dispatched = []
     for b in batches:
         inner = [e for e in evs if e.name != "engine.batch"
                  and e.line == b.line and _inside(e, b)]
-        assert [e.name for e in inner] == phases          # in order
+        # each step stages, copies and dispatches the batches it sends (the
+        # second one only while the first is in flight), then reads back one
+        sent = (len(inner) - 1) // 3
+        assert [e.name for e in inner] == phases[:3] * sent + phases[3:]
+        dispatched += [e.attrs["overlapped"] for e in inner
+                       if e.name == "engine.dispatch"]
         assert b.attrs["model"] == "m" and b.attrs["mode"] == "predict"
         assert b.attrs["bucket"] == 64
+    assert dispatched in ([0, 1], [0, 0])
+    assert eng.stats()["overlapped_batches"] == sum(dispatched)
     first, second = batches
     assert (first.attrs["rows"], second.attrs["rows"]) == (64, 56)
     # the first batch takes both requests' first rows; the second only

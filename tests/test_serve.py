@@ -160,6 +160,156 @@ def test_engine_fused_degree_counter(fitted):
                 f'mode="{mode}"}} {value}') in text
 
 
+class _Held:
+    """A cell's output whose ``is_ready()`` answers ``ready`` whatever the
+    device has done; ``np.asarray`` reads it back as usual."""
+
+    def __init__(self, out, ready):
+        self.out, self.ready = out, ready
+
+    def is_ready(self):
+        return self.ready
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self.out, dtype)
+
+
+def _held_engine(fitted, *, ready=False, modes=("predict",)):
+    """An engine over ``fitted`` whose warmed cells' outputs answer
+    ``is_ready()`` with ``ready``: False looks like a busy device, so the
+    engine always dispatches ahead; True never lets it."""
+    eng = _engine(fitted)
+    for name in fitted:
+        eng.warmup(name, modes=modes)
+    for key, cell in list(eng._cells.items()):
+        eng._cells[key] = lambda *a, _cell=cell: _Held(_cell(*a), ready)
+    return eng
+
+
+@pytest.mark.parametrize("mode", ["predict", "transform"])
+def test_engine_overlap_bit_identical(fitted, mode):
+    """Two models interleaved, requests split across the top bucket, every
+    batch dispatched while the one before it is in flight: each result is
+    the model's own ``predict`` / ``transform``, row for row."""
+    eng = _held_engine(fitted, modes=(mode,))
+    rng = np.random.default_rng(7)
+    reqs = []
+    for i in range(12):
+        name = ("blobs", "rings")[i % 2]
+        mdl, x = fitted[name]
+        n = int(rng.choice((5, 60, 129, 200, 290)))
+        rows = np.vstack([x, x])[(17 * i) % 300:][:n]
+        reqs.append((name, rows, eng.submit(name, rows, mode)))
+        if i % 3 == 2:
+            eng.step()
+    eng.drain()
+    for name, rows, t in reqs:
+        mdl = fitted[name][0]
+        want = mdl.predict(rows) if mode == "predict" else mdl.transform(rows)
+        np.testing.assert_array_equal(eng.take(t).values, want)
+    s = eng.stats()
+    assert 0 < s["overlapped_batches"] < s["batches"]
+    assert all(s["models"][m]["overlapped_batches"] > 0 for m in fitted)
+
+
+def test_engine_step_completes_the_oldest_batch(fitted):
+    """Each ``step`` reads back exactly the oldest batch and returns its
+    rows, with the next one in flight; a ticket whose last batch is in
+    flight is not finished; ``drain`` leaves nothing in flight; the totals
+    are those of an engine that never dispatches ahead."""
+    mb, xb = fitted["blobs"]
+    mr, xr = fitted["rings"]
+    eng = _held_engine(fitted)
+    big = np.vstack([xb, xb])[:290]
+    tb = eng.submit("blobs", big)
+    tr = eng.submit("rings", xr[:50])
+    assert eng.step() == 128                  # blobs 1/3; 2/3 in flight
+    assert [b.rows for b in eng._inflight] == [128]
+    with pytest.raises(KeyError, match="not finished"):
+        eng.take(tb)
+    assert eng.step() == 128                  # blobs 2/3; 3/3 in flight
+    with pytest.raises(KeyError, match="not finished"):
+        eng.take(tb)
+    assert eng.step() == 34                   # blobs 3/3; rings in flight
+    np.testing.assert_array_equal(eng.take(tb).values, mb.predict(big))
+    with pytest.raises(KeyError, match="not finished"):
+        eng.take(tr)
+    assert [(b.model, b.rows) for b in eng._inflight] == [("rings", 50)]
+    assert eng.drain() == 50
+    assert not eng._inflight and not eng.stats()["pending"]
+    np.testing.assert_array_equal(eng.take(tr).values, mr.predict(xr[:50]))
+    assert eng.step() == 0
+    ahead = eng.stats()
+    assert ahead["overlapped_batches"] == 3
+    serial = _held_engine(fitted, ready=True)
+    serial.submit("blobs", big)
+    serial.submit("rings", xr[:50])
+    assert serial.drain() == 340
+    serial_stats = serial.stats()
+    assert serial_stats["overlapped_batches"] == 0
+    for key in ("rows_served", "batches", "padded_rows", "model_switches"):
+        assert ahead[key] == serial_stats[key], key
+
+
+def test_engine_ring_never_hands_out_an_inflight_buffer(fitted):
+    """With every batch dispatched ahead and consecutive batches of one
+    shape, the staging ring's buffer for a new batch is never that of the
+    batch still in flight, and steady state allocates nothing."""
+    _, x = fitted["blobs"]
+    eng = _held_engine(fitted)
+    ring = eng._ring
+    real_get = ring.get
+    handed = []
+
+    def spy(rows, dim):
+        buf = real_get(rows, dim)
+        assert all(buf is not b.buf for b in eng._inflight)
+        handed.append(buf)
+        return buf
+
+    ring.get = spy
+    allocations = ring.allocations
+    for _ in range(3):
+        tickets = [eng.submit("blobs", np.vstack([x, x])[:512])
+                   for _ in range(2)]
+        eng.drain()
+        for t in tickets:
+            eng.take(t)
+    assert len(handed) == 3 * 8               # 1,024 rows in 128-row batches
+    assert len({id(b) for b in handed}) == 2
+    assert ring.allocations == allocations
+    assert eng.stats()["overlapped_batches"] == 3 * 7
+
+
+def test_engine_overlapped_batches_counter(fitted):
+    """A lone request that fits one batch never dispatches ahead; a second
+    group pending during a slow batch does, counted per (model, mode), in
+    ``stats()`` and in ``metrics_text()``."""
+    _, xb = fitted["blobs"]
+    _, xr = fitted["rings"]
+    eng = _held_engine(fitted)
+    t = eng.submit("blobs", xb[:40])
+    eng.drain()
+    eng.take(t)
+    assert eng.stats()["overlapped_batches"] == 0
+    assert eng.stats("blobs")["overlapped_batches"] == 0
+    assert ('engine_overlapped_batches_total{model="blobs",mode="predict"} 0'
+            in eng.metrics_text())
+    tickets = [eng.submit("blobs", xb[:40]), eng.submit("rings", xr[:40])]
+    eng.drain()
+    for t in tickets:
+        eng.take(t)
+    s = eng.stats()
+    assert s["overlapped_batches"] == 1 and s["batches"] == 3
+    assert s["models"]["rings"]["overlapped_batches"] == 1
+    assert s["models"]["blobs"]["overlapped_batches"] == 0
+    text = eng.metrics_text()
+    for model, mode, value in (("blobs", "predict", 0), ("rings", "predict", 1),
+                               ("rings", "transform", 0)):
+        assert (f'engine_overlapped_batches_total{{model="{model}",'
+                f'mode="{mode}"}} {value}') in text
+
+
 @pytest.fixture(scope="module")
 def zoo():
     """Three tiny fitted models of different d and K."""
